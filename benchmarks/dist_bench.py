@@ -128,9 +128,15 @@ print(json.dumps(rows))
 
 def _rows_forced(payload: str, ndevices: int) -> list[tuple]:
     """Run ``payload`` in a subprocess on ``ndevices`` forced host devices
-    and parse its last stdout line as the row list."""
+    and parse its last stdout line as the row list.
+
+    The child is a CPU rehearsal by design: it is pinned to
+    ``JAX_PLATFORMS=cpu`` (a parent that already ran groups on a TPU holds
+    the chip, so a child inheriting the platform would fail on the TPU
+    lock), and every row it returns names ``platform=cpu``."""
     repo = pathlib.Path(__file__).resolve().parent.parent
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndevices}"
     env["PYTHONPATH"] = str(repo / "src")
     out = subprocess.run([sys.executable, "-c", payload], env=env,
@@ -138,7 +144,8 @@ def _rows_forced(payload: str, ndevices: int) -> list[tuple]:
     if out.returncode != 0:
         raise RuntimeError(
             f"dist bench subprocess failed: {out.stderr[-500:]}")
-    return [tuple(r) for r in json.loads(out.stdout.strip().splitlines()[-1])]
+    rows = json.loads(out.stdout.strip().splitlines()[-1])
+    return [(name, us, f"{derived};platform=cpu") for name, us, derived in rows]
 
 
 def dist_rows():

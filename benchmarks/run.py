@@ -1,7 +1,6 @@
 """Benchmark harness: one function per paper table/figure + kernel timings
 + the unified-front-end groups + the dry-run roofline aggregation.  Prints
-``name,us_per_call,derived`` CSV rows (the contract consumed by
-EXPERIMENTS.md).
+``name,us_per_call,derived`` CSV rows.
 
 ``--smoke`` runs a fast subset (front-end dispatch, batched engine, kernel
 micro-times, the structural Table-1 rows) for the CI benchmark-smoke job:
@@ -127,4 +126,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -1,48 +1,29 @@
-"""jax version compatibility shims (0.4.x <-> 0.5+).
+"""Mesh and shard_map constructors in one place.
 
-The production code targets the modern jax API (``jax.shard_map``,
-``jax.sharding.AxisType``); the pinned CI / container toolchain ships a
-0.4.x jaxlib where those live under older names.  Every use of the
-affected APIs in this repo goes through this module.
+Every mesh of this repo is built with explicit ``Auto`` axis types, and
+every ``shard_map`` goes through :func:`shard_map_compat`, so a change of
+the JAX mesh API touches this module only.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # jax 0.4.x: no explicit axis types
-    _AxisType = None
-
-HAS_AXIS_TYPES = _AxisType is not None
+from jax.sharding import AbstractMesh, AxisType
 
 
 def make_mesh_compat(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=(_AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def abstract_mesh_compat(axis_shapes, axis_names):
-    """Version-portable ``jax.sharding.AbstractMesh`` constructor."""
-    from jax.sharding import AbstractMesh
-    if HAS_AXIS_TYPES:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names),
-                            axis_types=(_AxisType.Auto,) * len(axis_names))
-    return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    """``jax.sharding.AbstractMesh`` with ``Auto`` axis types."""
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names),
+                        axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` (new) / ``jax.experimental.shard_map`` (0.4.x).
-
-    ``check`` maps onto ``check_vma`` on the new API and ``check_rep`` on
-    the old one (same semantics: validate replication of outputs).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check)
+    """``jax.shard_map``; ``check`` maps onto ``check_vma`` (validate the
+    replication of outputs)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

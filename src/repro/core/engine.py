@@ -52,7 +52,7 @@ from .pcg import ghysels_pcg
 from .plcg import plcg
 from .precision import as_precision_policy
 from .precond import as_preconditioner
-from .plcg_scan import plcg_solve
+from .plcg_scan import plcg_solve, resolve_backend
 from .plcg_scan import plcg_scan as _plcg_scan_engine
 from .plminres import plminres
 from .results import SolveResult
@@ -786,26 +786,16 @@ def _batched_engine(method_name: str, matvec, l: int, iters: int, sigma,
         build)
 
 
-def _solve_batched_vmap(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
-                        maxiter, M, l, sigma, spectrum, backend,
-                        restart=None, rr_period=None, precision=None,
-                        exploit_symmetry: bool = True, unroll: int = 1,
-                        ritz_refresh: bool = True,
-                        get_engine=None, **options) -> SolveResult:
-    """One jitted ``vmap`` of the scan engine over the stacked RHS.
-
-    A single XLA compilation covers all ``nrhs`` systems; converged lanes
-    freeze via the engine's per-lane commit select while the remaining
-    lanes keep iterating.  Runs ONE sweep always: with ``restart=`` /
-    ``rr_period=`` (normalized by ``_prepare_restart``) each lane
-    re-seeds itself in-trace on breakdown / on the replacement period --
-    recovery is per lane, inside the same compiled program, never a
-    second sweep.
-
-    ``get_engine`` (internal) lets a prepared :class:`session.Solver`
-    inject its strongly-held jitted engine in place of the weak-key cache
-    lookup; it receives exactly :func:`_batched_engine`'s arguments.
-    """
+def _batched_program(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
+                     maxiter, M, l, sigma, spectrum, backend,
+                     restart=None, rr_period=None, precision=None,
+                     exploit_symmetry: bool = True, unroll: int = 1,
+                     ritz_refresh: bool = True,
+                     get_engine=None, **options):
+    """The jitted batched engine and its operands for one stacked RHS:
+    ``(fn, args, sigma, stab)`` with ``fn(*args)`` the one call that
+    :func:`_solve_batched_vmap` runs (and ``fn.lower(*args)`` the program
+    a prepared session reports, see ``session.Solver.lower``)."""
     if options:
         # don't silently drop flags the single-RHS call would honor
         # (trace_gaps, record_G, max_restarts, ...)
@@ -845,7 +835,38 @@ def _solve_batched_vmap(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
                sig, tol, M, exploit_symmetry, unroll, backend,
                getattr(A, "stencil2d", None), restart, rr_period,
                ritz_refresh, maxiter if stab else None, precision, bind)
-    out = fn(A.context, Bj, X0) if bind else fn(Bj, X0)
+    args = (A.context, Bj, X0) if bind else (Bj, X0)
+    return fn, args, sig, stab
+
+
+def _solve_batched_vmap(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
+                        maxiter, M, l, sigma, spectrum, backend,
+                        restart=None, rr_period=None, precision=None,
+                        exploit_symmetry: bool = True, unroll: int = 1,
+                        ritz_refresh: bool = True,
+                        get_engine=None, **options) -> SolveResult:
+    """One jitted ``vmap`` of the scan engine over the stacked RHS.
+
+    A single XLA compilation covers all ``nrhs`` systems; converged lanes
+    freeze via the engine's per-lane commit select while the remaining
+    lanes keep iterating.  Runs ONE sweep always: with ``restart=`` /
+    ``rr_period=`` (normalized by ``_prepare_restart``) each lane
+    re-seeds itself in-trace on breakdown / on the replacement period --
+    recovery is per lane, inside the same compiled program, never a
+    second sweep.
+
+    ``get_engine`` (internal) lets a prepared :class:`session.Solver`
+    inject its strongly-held jitted engine in place of the weak-key cache
+    lookup; it receives exactly :func:`_batched_engine`'s arguments.
+    """
+    fn, args, sig, stab = _batched_program(
+        spec, A, B, x0=x0, tol=tol, maxiter=maxiter, M=M, l=l, sigma=sigma,
+        spectrum=spectrum, backend=backend, restart=restart,
+        rr_period=rr_period, precision=precision,
+        exploit_symmetry=exploit_symmetry, unroll=unroll,
+        ritz_refresh=ritz_refresh, get_engine=get_engine, **options)
+    precision = as_precision_policy(precision)
+    out = fn(*args)
     resn = np.asarray(out.resnorms)                     # (nrhs, iters)
     conv = np.asarray(out.converged)
     brk = np.asarray(out.breakdown)
@@ -865,8 +886,8 @@ def _solve_batched_vmap(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
         # legitimate exact-zero residual in the trace
         resnorms = [[float(r) for r in row[l: l + int(k) + 1]]
                     for row, k in zip(resn, k_done)]
-        restarts_pl = np.zeros(Bj.shape[0], dtype=int)
-        repl_pl = np.zeros(Bj.shape[0], dtype=int)
+        restarts_pl = np.zeros(conv.shape[0], dtype=int)
+        repl_pl = np.zeros(conv.shape[0], dtype=int)
     return SolveResult(
         x=out.x,
         resnorms=resnorms,
@@ -876,9 +897,10 @@ def _solve_batched_vmap(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
         restarts=int(restarts_pl.sum()),
         replacements=int(repl_pl.sum()),
         info={"method": f"p({l})-CG[scan,vmap]", "l": l,
-              "sigma": list(sig), "backend": backend, "batched": "vmap",
+              "sigma": list(sig), "backend": resolve_backend(backend),
+              "batched": "vmap",
               "prec": getattr(M, "name", None) if M is not None else None,
-              "nrhs": int(Bj.shape[0]),
+              "nrhs": int(conv.shape[0]),
               "restart": restart, "residual_replacement": rr_period,
               "precision": None if precision.is_default else precision,
               "per_rhs_converged": conv,
@@ -961,7 +983,7 @@ def _run_plcg_scan(A, b, x0, *, tol, maxiter, M, l, sigma, spectrum,
         restarts=info["restarts"],
         replacements=info.get("replacements", 0),
         info={"method": f"p({l})-CG[scan]", "l": l, "sigma": sig,
-              "backend": backend,
+              "backend": resolve_backend(backend),
               "restart": restart,
               "residual_replacement": residual_replacement,
               "precision": (None if pp.is_default else pp),

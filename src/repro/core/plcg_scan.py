@@ -93,6 +93,19 @@ def _default_dot(a, b):
     return jnp.vdot(a, b)
 
 
+def resolve_backend(backend: Optional[str]) -> Optional[str]:
+    """The kernel tier ``backend`` runs on this platform: ``"auto"`` is
+    ``"pallas"`` on TPU and ``"ref"`` elsewhere; the others name
+    themselves."""
+    if backend == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
+    if backend not in BACKENDS:
+        raise ValueError(
+            "backend must be None, 'auto', 'pallas', 'ref' or 'fused', "
+            f"got {backend!r}")
+    return backend
+
+
 def plcg_scan(
     matvec: Callable,
     b: jax.Array,
@@ -209,12 +222,7 @@ def plcg_scan(
         raise ValueError(f"restart must be >= 0, got {restart}")
     if rr_period is not None and int(rr_period) < 1:
         raise ValueError(f"rr_period must be >= 1, got {rr_period}")
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if backend not in BACKENDS:
-        raise ValueError(
-            "backend must be None, 'auto', 'pallas', 'ref' or 'fused', "
-            f"got {backend!r}")
+    backend = resolve_backend(backend)
     use_fused = backend == "fused" and dot_local is None
     use_kernels = backend in ("pallas", "ref") and dot_local is None
     if use_kernels:

@@ -377,6 +377,38 @@ class Solver:
 
     __call__ = solve
 
+    def lower(self, b, x0=None):
+        """Lower, without running it, the program that ``solve(b, x0)``
+        runs on one device: a ``jax.stages.Lowered`` whose ``.compile()``
+        shows the kernel launches (``as_text()``) and the device memory
+        (``memory_analysis()``) of the solve.  ``b`` is ``(n,)`` or a
+        stacked ``(nrhs, n)`` batch, as for :meth:`solve`; a flush of a
+        :class:`SolverPool` runs the batched program of its padded batch.
+        Single-device ``plcg_scan`` sessions only."""
+        import jax.numpy as jnp
+        if self._mesh_session is not None or self.spec.name != "plcg_scan":
+            raise NotImplementedError(
+                "Solver.lower covers single-device plcg_scan sessions")
+        op = self._ensure_op(b)
+        bj = jnp.asarray(b)
+        if bj.ndim == 2:
+            opts = {key: v for key, v in self.options.items()
+                    if key in ("exploit_symmetry", "unroll", "ritz_refresh")}
+            fn, args, _, _ = engine._batched_program(
+                self.spec, op, bj, x0=x0, tol=self.tol, maxiter=self.maxiter,
+                M=self.M, l=self.l, sigma=self.sigma, spectrum=self.spectrum,
+                backend=self.backend, restart=self.restart,
+                rr_period=self.residual_replacement,
+                precision=self.precision,
+                get_engine=self._batched_engine_getter(), **opts)
+            return fn.lower(*args)
+        sweep = self._single_sweep(self.tol, self.maxiter)
+        x0j = jnp.zeros_like(bj) if x0 is None else jnp.asarray(x0)
+        args = (bj, x0j, self.maxiter)
+        if is_bindable(op):
+            args = (op.context,) + args
+        return sweep.lower(*args)
+
     # ---- micro-batched dispatch -----------------------------------------
 
     def submit(self, b, x0=None, *, _owner=None) -> SolveHandle:

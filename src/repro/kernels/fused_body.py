@@ -36,11 +36,17 @@ All math runs in ``promote_types(dtype, float32)`` -- f64 solver paths
 (x64, interpret mode) keep full precision so ``backend="fused"`` is
 bit-comparable to the inline jnp body.
 
-Grid: 1-D over row-blocks of n (over grid rows of the (H, W) domain when
-the stencil is fused, so vertical stencil neighbors come from the
-prev/next block trick of ``stencil2d``).  The dot payload accumulates
-across grid steps into a revisited output block -- the canonical Pallas
-reduction pattern.
+Grid: 1-D over row-blocks of n, each a multiple of 8 rows
+(``blocks.row_block``); a partial last block is masked out of the dots.
+With the stencil fused, the SPMV runs in the same ``(bs, 1)`` column
+layout as the windows (no lane-to-sublane relayout, which Mosaic
+refuses): the neighbours of grid point g sit at g +- 1 and g +- W rows,
+the block spans whole halo blocks of ``hb = roundup(W, 8)`` rows, and the
+W rows above and below come from the previous and next halo blocks of
+``Zw`` itself.  Boundary masks follow from the global row index (first
+and last grid row, first and last grid column).  The dot payload
+accumulates across grid steps into a revisited output block -- the
+canonical Pallas reduction pattern.
 """
 from __future__ import annotations
 
@@ -50,15 +56,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .blocks import SUBLANES, block_rows, row_block
+
 #: scal layout: [steady, s_warm, gam, dlt, dsub, gcc, invd, g_0 .. g_{2l-1}]
 #: (invd is the scalar inverse diagonal for diag="scalar", else unused)
 N_FIXED_SCALARS = 7
 
 
-def _make_kernel(l: int, has_zh: bool, has_stencil: bool, diag: str,
-                 nblocks: int, acc):
+def _make_kernel(l: int, has_zh: bool, stencil, diag: str, n: int, bs: int,
+                 acc):
+    """``stencil`` is None or ``(W, hb)``: grid width and halo rows."""
     m = 2 * l + 1
     has_diag = diag != "none"
+    has_stencil = stencil is not None
 
     def kernel(*refs):
         it = iter(refs)
@@ -68,7 +78,7 @@ def _make_kernel(l: int, has_zh: bool, has_stencil: bool, diag: str,
         zh_ref = next(it) if has_zh else None
         invd_ref = next(it) if diag == "vector" else None
         if has_stencil:
-            zp_ref, zc_ref, zn_ref = next(it), next(it), next(it)
+            zp_ref, zn_ref = next(it), next(it)     # halo blocks of Zw
         elif has_diag:
             th_ref = next(it)                   # t computed in-kernel
         else:
@@ -80,6 +90,7 @@ def _make_kernel(l: int, has_zh: bool, has_stencil: bool, diag: str,
         d_ref = next(it)
 
         i = pl.program_id(0)
+        g_row = block_rows(i, bs)
         scal = scal_ref[...].astype(acc)            # (1, 7 + 2l)
         steady = scal[0, 0] > 0.5
         s_warm, gam, dlt = scal[0, 1], scal[0, 2], scal[0, 3]
@@ -91,17 +102,21 @@ def _make_kernel(l: int, has_zh: bool, has_stencil: bool, diag: str,
 
         # ---- (K1) SPMV: in-kernel 5-point stencil or streamed t --------
         if has_stencil:
-            xc = zc_ref[...].astype(acc)            # (bh, W2d)
-            top = jnp.where(i == 0, jnp.zeros_like(xc[-1:, :]),
-                            zp_ref[-1:, :].astype(acc))
-            bot = jnp.where(i == nblocks - 1, jnp.zeros_like(xc[:1, :]),
-                            zn_ref[:1, :].astype(acc))
-            up = jnp.concatenate([top, xc[:-1]], axis=0)
-            down = jnp.concatenate([xc[1:], bot], axis=0)
-            zc_col = jnp.zeros_like(xc[:, :1])      # Dirichlet halos
-            left = jnp.concatenate([zc_col, xc[:, :-1]], axis=1)
-            right = jnp.concatenate([xc[:, 1:], zc_col], axis=1)
-            traw = (4.0 * xc - up - down - left - right).reshape(-1, 1)
+            W, hb = stencil
+            zc = Z[:, :1]                           # (bs, 1) = z_i
+            zp = zp_ref[:, :1].astype(acc)          # (hb, 1) rows above
+            zn = zn_ref[:, :1].astype(acc)          # (hb, 1) rows below
+            up = jnp.concatenate([zp[hb - W:], zc[:bs - W]], axis=0)
+            down = jnp.concatenate([zc[W:], zn[:W]], axis=0)
+            left = jnp.concatenate([zp[hb - 1:], zc[:-1]], axis=0)
+            right = jnp.concatenate([zc[1:], zn[:1]], axis=0)
+            col = jax.lax.rem(g_row, jnp.int32(W))
+            zero = jnp.zeros_like(zc)               # Dirichlet boundary
+            up = jnp.where(g_row >= W, up, zero)
+            down = jnp.where(g_row < n - W, down, zero)
+            left = jnp.where(col != 0, left, zero)
+            right = jnp.where(col != W - 1, right, zero)
+            traw = 4.0 * zc - up - down - left - right
             # the SPMV stream is storage-dtype under the precision
             # policy: round the in-kernel result exactly like the
             # streamed-t tiers store it (identity when storage is the
@@ -151,8 +166,10 @@ def _make_kernel(l: int, has_zh: bool, has_stencil: bool, diag: str,
         # windows); identity casts when storage == accumulation dtype
         V2s = V2.astype(vo_ref.dtype).astype(acc)
         Z2s = Z2.astype(zo_ref.dtype).astype(acc)
-        vd = (V2s[:, :l + 1] * lhs).sum(axis=0)     # (l+1,)
-        zd = (Z2s[:, :l] * lhs).sum(axis=0)         # (l,)
+        # rows past n (a partial last block) hold unspecified values
+        ok = g_row < n
+        vd = jnp.where(ok, V2s[:, :l + 1] * lhs, 0.0).sum(axis=0)  # (l+1,)
+        zd = jnp.where(ok, Z2s[:, :l] * lhs, 0.0).sum(axis=0)      # (l,)
 
         @pl.when(i == 0)
         def _init():
@@ -187,8 +204,9 @@ def fused_body(Vw, Zw, scal, Zhw=None, t=None, t_hat=None, invd=None, *,
         the (K1) SPMV runs in-kernel.
       diag: "none" | "scalar" | "vector" -- in-kernel diagonal
         preconditioner mode (requires ``Zhw``).
-      bn: row-block size (rounded down to divide n; with the stencil
-        fused, blocks are whole grid rows, ``bn // W`` of them).
+      bn: target row-block size (a multiple of 8, or n when n <= bn; with
+        the stencil fused, a whole number of ``roundup(W, 8)``-row halo
+        blocks).  Any n compiles: the last block may be partial.
 
     Returns:
       (Vw2, Zw2, Zhw2 | None, dots) with ``dots`` the (2l+1,) payload
@@ -220,15 +238,17 @@ def fused_body(Vw, Zw, scal, Zhw=None, t=None, t_hat=None, invd=None, *,
         H, W2d = stencil_hw
         if H * W2d != n:
             raise ValueError(f"stencil_hw {stencil_hw} != n={n}")
-        bh = max(min(bn // W2d, H), 1)
-        while H % bh:
-            bh -= 1
-        nblocks, bs = H // bh, bh * W2d
+        if H < 2:
+            raise ValueError(f"the fused stencil needs >= 2 grid rows, got "
+                             f"stencil_hw={stencil_hw}")
+        hb = -(-W2d // SUBLANES) * SUBLANES     # halo block >= one grid row
+        hb = n if hb >= n else hb
+        k = max(bn // hb, 1)
+        bs = n if k * hb >= n else k * hb
+        nhalo = pl.cdiv(n, hb)
     else:
-        bs = min(bn, n)
-        while n % bs:
-            bs //= 2
-        nblocks = n // bs
+        bs = row_block(n, bn)
+    nblocks = pl.cdiv(n, bs)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     acc = jnp.promote_types(Vw.dtype, jnp.float32)
@@ -247,14 +267,14 @@ def fused_body(Vw, Zw, scal, Zhw=None, t=None, t_hat=None, invd=None, *,
         in_specs.append(pl.BlockSpec((bs, 1), row))
         operands.append(invd.reshape(n, 1))
     if has_stencil:
-        z2d = Zw[:, 0].reshape(H, W2d)
+        # the hb rows just above / below block i, read from Zw itself
         in_specs += [
-            pl.BlockSpec((bh, W2d), lambda i: (jnp.maximum(i - 1, 0), 0)),
-            pl.BlockSpec((bh, W2d), row),
-            pl.BlockSpec((bh, W2d),
-                         lambda i: (jnp.minimum(i + 1, nblocks - 1), 0)),
+            pl.BlockSpec((hb, l + 1),
+                         lambda i: (jnp.maximum(i * k - 1, 0), 0)),
+            pl.BlockSpec((hb, l + 1),
+                         lambda i: (jnp.minimum((i + 1) * k, nhalo - 1), 0)),
         ]
-        operands += [z2d, z2d, z2d]
+        operands += [Zw, Zw]
     elif has_diag:
         in_specs.append(pl.BlockSpec((bs, 1), row))
         operands.append(t_hat.reshape(n, 1))
@@ -276,7 +296,8 @@ def fused_body(Vw, Zw, scal, Zhw=None, t=None, t_hat=None, invd=None, *,
     out_shape.append(jax.ShapeDtypeStruct((1, m), acc))
 
     outs = pl.pallas_call(
-        _make_kernel(l, has_zh, has_stencil, diag, nblocks, acc),
+        _make_kernel(l, has_zh, (W2d, hb) if has_stencil else None, diag,
+                     n, bs, acc),
         grid=(nblocks,),
         in_specs=in_specs,
         out_specs=out_specs,

@@ -23,6 +23,7 @@ running anything.
 from __future__ import annotations
 
 import jax
+from jax.extend import core as jex_core
 
 
 def jit_cache_size(fn) -> int:
@@ -209,9 +210,9 @@ def _collect_scan_bodies(jaxpr, out: list, seen: set) -> None:
 
 def _sub_jaxprs(obj):
     """Yield every Jaxpr reachable from an eqn params value."""
-    if isinstance(obj, jax.core.Jaxpr):
+    if isinstance(obj, jex_core.Jaxpr):
         yield obj
-    elif isinstance(obj, jax.core.ClosedJaxpr):
+    elif isinstance(obj, jex_core.ClosedJaxpr):
         yield obj.jaxpr
     elif isinstance(obj, dict):
         for v in obj.values():

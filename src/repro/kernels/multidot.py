@@ -5,9 +5,9 @@ in a single pass over ``z``: the window matrix W (the sliding-window basis
 vectors stacked **lane-major**, shape ``(n, m)`` so the m-entry band of one
 grid point is contiguous) streams through VMEM chunk-by-chunk together with
 exactly one copy of z.  A naive implementation reads z once *per dot*;
-fusing cuts HBM traffic from 2(2l+1)n to (2l+2)n words -- the memory-bound
-win reported in EXPERIMENTS.md SPerf (beyond-paper optimization: the paper
-fuses the *reduction*, we additionally fuse the local reads).
+fusing cuts HBM traffic from 2(2l+1)n to (2l+2)n words (beyond-paper
+optimization: the paper fuses the *reduction*, we additionally fuse the
+local reads).
 
 Accumulation dtype is ``promote_types(dtype, float32)``: bf16/f32 inputs
 accumulate in f32 like the TPU MXU, f64 inputs (x64 solver paths, interpret
@@ -27,8 +27,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .blocks import block_rows, row_block
 
-def _kernel(acc, w_ref, z_ref, o_ref):
+
+def _kernel(acc, n, bn, w_ref, z_ref, o_ref):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -37,7 +39,8 @@ def _kernel(acc, w_ref, z_ref, o_ref):
 
     w = w_ref[...].astype(acc)                    # (bn, m)
     z = z_ref[...].astype(acc)                    # (bn, 1)
-    o_ref[...] += (w * z).sum(axis=0, keepdims=True)
+    wz = jnp.where(block_rows(i, bn) < n, w * z, 0.0)  # partial last block
+    o_ref[...] += wz.sum(axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -45,15 +48,13 @@ def multidot(W, z, *, bn: int = 2048, interpret: bool | None = None):
     """out (m,) = W.T (m, n) @ z (n,) for lane-major W (n, m), one fused
     pass, ``promote_types(dtype, f32)`` accumulation."""
     n, m = W.shape
-    bn = min(bn, n)
-    while n % bn:
-        bn //= 2
+    bn = row_block(n, bn)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     acc = jnp.promote_types(W.dtype, jnp.float32)
     out = pl.pallas_call(
-        functools.partial(_kernel, acc),
-        grid=(n // bn,),
+        functools.partial(_kernel, acc, n, bn),
+        grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((bn, m), lambda i: (i, 0)),
             pl.BlockSpec((bn, 1), lambda i: (i, 0)),

@@ -7,8 +7,11 @@ performs the ``ppermute`` exchange; the kernel is purely local).
 
 TPU mapping: the grid tiles the local block over rows; each step holds a
 (bh, W) tile in VMEM plus its row-neighbors, so vertical neighbor access
-never leaves VMEM.  W should be a multiple of 128 (lane width); bh a
-multiple of 8 (f32 sublanes).
+never leaves VMEM.  A tile spans the whole local width W (any W, e.g. the
+500- or 875-wide blocks of the paper's grids on a 2x2 mesh), and bh is a
+multiple of 8 sized to a VMEM budget (``blocks.row_block``); when bh does
+not divide H the last tile is partial, and the south halo is applied by
+global row index, not by tile position.
 
 ``stencil2d_batched`` is the multi-RHS variant: the B lanes of a
 ``(B, H, W)`` batch ride the leading block axis (the same lane-leading
@@ -27,18 +30,32 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .blocks import block_rows, row_block
 
-def _kernel(nblocks, xp_ref, xc_ref, xn_ref, hn_ref, hs_ref, hw_ref, he_ref,
+#: bytes of one (bh, W) f32 tile (lanes padded to 128): 4 such tiles,
+#: double-buffered, plus the shifted temporaries stay inside the default
+#: scoped VMEM of a v5e core
+TILE_BYTES = 512 * 1024
+
+
+def _tile_rows(H: int, W: int, bh: int, lanes: int = 1) -> int:
+    """Row-block height: at most ``bh`` and the VMEM tile budget."""
+    padded_w = -(-W // 128) * 128
+    budget = max(TILE_BYTES // (4 * padded_w * lanes), 1)
+    return row_block(H, min(bh, budget))
+
+
+def _kernel(H, bh, xp_ref, xc_ref, xn_ref, hn_ref, hs_ref, hw_ref, he_ref,
             o_ref):
     i = pl.program_id(0)
     acc = jnp.promote_types(xc_ref.dtype, jnp.float32)
     xc = xc_ref[...].astype(acc)
     top_halo = jnp.where(i == 0, hn_ref[...].astype(acc),
                          xp_ref[-1:, :].astype(acc))
-    bot_halo = jnp.where(i == nblocks - 1, hs_ref[...].astype(acc),
-                         xn_ref[:1, :].astype(acc))
     up = jnp.concatenate([top_halo, xc[:-1]], axis=0)
-    down = jnp.concatenate([xc[1:], bot_halo], axis=0)
+    down = jnp.concatenate([xc[1:], xn_ref[:1, :].astype(acc)], axis=0)
+    down = jnp.where(block_rows(i, bh) == H - 1, hs_ref[...].astype(acc),
+                     down)
     left = jnp.concatenate([hw_ref[...].astype(acc), xc[:, :-1]], axis=1)
     right = jnp.concatenate([xc[:, 1:], he_ref[...].astype(acc)], axis=1)
     o_ref[...] = (4.0 * xc - up - down - left - right).astype(o_ref.dtype)
@@ -52,10 +69,8 @@ def stencil2d(x, halo_n, halo_s, halo_w, halo_e, *, bh: int = 256,
     x: (H, W) local block; halo_n/halo_s: (W,); halo_w/halo_e: (H,).
     """
     H, W = x.shape
-    bh = min(bh, H)
-    while H % bh:
-        bh //= 2
-    nblocks = H // bh
+    bh = _tile_rows(H, W, bh)
+    nblocks = pl.cdiv(H, bh)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     dtype = x.dtype
@@ -63,7 +78,7 @@ def stencil2d(x, halo_n, halo_s, halo_w, halo_e, *, bh: int = 256,
     hs = halo_s.reshape(1, W).astype(dtype)
     hw = halo_w.reshape(H, 1).astype(dtype)
     he = halo_e.reshape(H, 1).astype(dtype)
-    kernel = functools.partial(_kernel, nblocks)
+    kernel = functools.partial(_kernel, H, bh)
     return pl.pallas_call(
         kernel,
         grid=(nblocks,),
@@ -82,17 +97,18 @@ def stencil2d(x, halo_n, halo_s, halo_w, halo_e, *, bh: int = 256,
     )(x, x, x, hn, hs, hw, he)
 
 
-def _kernel_batched(nblocks, xp_ref, xc_ref, xn_ref, hn_ref, hs_ref, hw_ref,
+def _kernel_batched(H, bh, xp_ref, xc_ref, xn_ref, hn_ref, hs_ref, hw_ref,
                     he_ref, o_ref):
     i = pl.program_id(0)
     acc = jnp.promote_types(xc_ref.dtype, jnp.float32)
     xc = xc_ref[...].astype(acc)                            # (B, bh, W)
     top_halo = jnp.where(i == 0, hn_ref[...].astype(acc),
                          xp_ref[:, -1:, :].astype(acc))
-    bot_halo = jnp.where(i == nblocks - 1, hs_ref[...].astype(acc),
-                         xn_ref[:, :1, :].astype(acc))
     up = jnp.concatenate([top_halo, xc[:, :-1, :]], axis=1)
-    down = jnp.concatenate([xc[:, 1:, :], bot_halo], axis=1)
+    down = jnp.concatenate([xc[:, 1:, :], xn_ref[:, :1, :].astype(acc)],
+                           axis=1)
+    row = i * bh + jax.lax.broadcasted_iota(jnp.int32, (1, bh, 1), 1)
+    down = jnp.where(row == H - 1, hs_ref[...].astype(acc), down)
     left = jnp.concatenate([hw_ref[...].astype(acc), xc[:, :, :-1]], axis=2)
     right = jnp.concatenate([xc[:, :, 1:], he_ref[...].astype(acc)], axis=2)
     o_ref[...] = (4.0 * xc - up - down - left - right).astype(o_ref.dtype)
@@ -108,10 +124,8 @@ def stencil2d_batched(x, halo_n, halo_s, halo_w, halo_e, *, bh: int = 256,
     single-lane kernel -- lanes only widen each block to (B, bh, W).
     """
     B, H, W = x.shape
-    bh = min(bh, H)
-    while H % bh:
-        bh //= 2
-    nblocks = H // bh
+    bh = _tile_rows(H, W, bh, lanes=B)
+    nblocks = pl.cdiv(H, bh)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     dtype = x.dtype
@@ -119,7 +133,7 @@ def stencil2d_batched(x, halo_n, halo_s, halo_w, halo_e, *, bh: int = 256,
     hs = halo_s.reshape(B, 1, W).astype(dtype)
     hw = halo_w.reshape(B, H, 1).astype(dtype)
     he = halo_e.reshape(B, H, 1).astype(dtype)
-    kernel = functools.partial(_kernel_batched, nblocks)
+    kernel = functools.partial(_kernel_batched, H, bh)
     return pl.pallas_call(
         kernel,
         grid=(nblocks,),

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .blocks import row_block
+
 
 def _kernel(acc, v_ref, z_ref, g_ref, o_ref):
     V = v_ref[...].astype(acc)                    # (bn, m)
@@ -33,9 +35,7 @@ def window_axpy(V, z, g, gcc, *, bn: int = 2048,
                 interpret: bool | None = None):
     """v_new (n,) = (z - V @ g) / gcc ; lane-major V (n, m), g (m,)."""
     n, m = V.shape
-    bn = min(bn, n)
-    while n % bn:
-        bn //= 2
+    bn = row_block(n, bn)         # a partial last block writes only rows < n
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     acc = jnp.promote_types(V.dtype, jnp.float32)
@@ -43,7 +43,7 @@ def window_axpy(V, z, g, gcc, *, bn: int = 2048,
                              jnp.asarray([gcc], acc)]).reshape(1, m + 1)
     out = pl.pallas_call(
         functools.partial(_kernel, acc),
-        grid=(n // bn,),
+        grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((bn, m), lambda i: (i, 0)),
             pl.BlockSpec((bn, 1), lambda i: (i, 0)),

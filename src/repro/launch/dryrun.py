@@ -8,7 +8,9 @@ mesh) cell and extract memory / cost / collective-schedule evidence.
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-14b --shape train_4k --mesh multi
 
 Results are cached as JSON under experiments/dryrun/<mesh>/<arch>__<shape>.json
-and aggregated by benchmarks/roofline.py into EXPERIMENTS.md tables.
+and aggregated by benchmarks/roofline.py into roofline tables.  The roofline
+terms use the published peaks of the chip the production mesh models
+(``repro.launch.peaks.TARGET_KIND``), not of the host that compiles it.
 """
 import argparse
 import dataclasses
@@ -22,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCHS, get_config
 from repro.launch import hlo_analysis
+from repro.launch.peaks import roofline_terms
 from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import SHAPES, input_specs, shape_applicable
 from repro.launch.steps import build_decode_step, build_prefill_step, build_train_step
@@ -47,11 +50,6 @@ MICROBATCH = {
     "whisper-large-v3": 4,
     "chatglm3-6b": 2,
 }
-
-# v5e-class hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s/link
 
 
 def _attach(tree_abs, tree_shard):
@@ -181,9 +179,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: pathlib.Path,
             "params": {"total": N, "active": Na},
             "model_flops_per_device": model_flops_dev,
             "roofline": {
-                "t_compute_s": st.flops / PEAK_FLOPS,
-                "t_memory_s": st.traffic_bytes / HBM_BW,
-                "t_collective_s": st.total_collective_bytes / ICI_BW,
+                **roofline_terms(st),
                 "model_flops_ratio": (model_flops_dev / st.flops
                                       if st.flops else None),
             },

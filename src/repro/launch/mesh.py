@@ -3,18 +3,14 @@
 Defined as functions (never module-level constants) so importing this
 module does not touch jax device state.
 
-jax version compat: ``jax.sharding.AxisType`` (and the ``axis_types``
-kwarg of ``jax.make_mesh`` / the modern ``AbstractMesh`` signature) only
-exist on jax >= 0.5; on the 0.4.x line meshes take no axis types and
-``AbstractMesh`` takes a ``((name, size), ...)`` shape tuple.  The
-``make_mesh_compat`` / ``abstract_mesh_compat`` helpers below paper over
-the difference and are the only mesh constructors the rest of the repo
-(and the test suite) should use.
+``make_mesh_compat`` / ``abstract_mesh_compat`` (re-exported from
+``repro.compat``) build every mesh with explicit ``Auto`` axis types and
+are the only mesh constructors the rest of the repo (and the test suite)
+should use.
 """
 from __future__ import annotations
 
-from ..compat import (HAS_AXIS_TYPES, abstract_mesh_compat,  # noqa: F401
-                      make_mesh_compat)
+from ..compat import abstract_mesh_compat, make_mesh_compat  # noqa: F401
 
 
 def make_production_mesh(*, multi_pod: bool = False):

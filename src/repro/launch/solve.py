@@ -119,7 +119,7 @@ def main(argv=None):
     if args.dryrun:
         from repro.distributed import DistPoisson, plcg_mesh_sweep
         from repro.launch import hlo_analysis
-        from repro.launch.dryrun import HBM_BW, ICI_BW, PEAK_FLOPS
+        from repro.launch.peaks import roofline_terms
         mesh = make_solver_mesh(multi_pod=args.multi_pod)
         px, py = mesh.shape["data"], mesh.shape["model"]
         nx = max(args.nx, px * 128)       # production-scale local blocks
@@ -143,11 +143,7 @@ def main(argv=None):
                     "traffic_bytes_per_device": st.traffic_bytes,
                     "collective_bytes": dict(st.collective_bytes),
                     "collective_counts": dict(st.collective_counts)},
-            "roofline": {
-                "t_compute_s": st.flops / PEAK_FLOPS,
-                "t_memory_s": st.traffic_bytes / HBM_BW,
-                "t_collective_s": st.total_collective_bytes / ICI_BW,
-            },
+            "roofline": roofline_terms(st),
         }
         out = pathlib.Path("experiments/dryrun/solver")
         out.mkdir(parents=True, exist_ok=True)
@@ -293,4 +289,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

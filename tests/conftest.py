@@ -32,9 +32,11 @@ def x64():
 @pytest.fixture(scope="session")
 def dist_env():
     """Environment for the multi-device subprocess tests: 8 forced host
-    devices + src on PYTHONPATH."""
+    devices + src on PYTHONPATH, pinned to the CPU (the children are CPU
+    rehearsals by design; on a TPU host the parent may hold the chip)."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = DIST_XLA_FLAGS
     env["PYTHONPATH"] = os.path.join(repo, "src")
     return env

@@ -16,7 +16,8 @@ from repro.kernels.window_axpy import window_axpy
 KEY = jax.random.PRNGKey(7)
 
 
-@pytest.mark.parametrize("shape", [(32, 128), (64, 128), (128, 256), (40, 128)])
+@pytest.mark.parametrize("shape", [(32, 128), (64, 128), (128, 256), (40, 128),
+                                   (20, 36)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("bh", [8, 16])
 def test_stencil2d(shape, dtype, bh):
@@ -46,7 +47,8 @@ def test_stencil2d_matches_poisson_operator():
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("m,n", [(3, 1024), (5, 4096), (9, 2048), (7, 1536)])
+@pytest.mark.parametrize("m,n", [(3, 1024), (5, 4096), (9, 2048), (7, 1536),
+                                 (7, 1001)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_multidot(m, n, dtype):
     W = jax.random.normal(KEY, (n, m), jnp.float32).astype(dtype)
@@ -75,7 +77,7 @@ def test_multidot_preserves_f64():
         jax.config.update("jax_enable_x64", old)
 
 
-@pytest.mark.parametrize("m,n", [(2, 1024), (6, 4096), (10, 2048)])
+@pytest.mark.parametrize("m,n", [(2, 1024), (6, 4096), (10, 2048), (6, 1001)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_window_axpy(m, n, dtype):
     V = jax.random.normal(KEY, (n, m), jnp.float32).astype(dtype)
@@ -184,10 +186,13 @@ def test_fused_body_matches_oracle(l, steady, prec):
                                    err_msg=lab)
 
 
-@pytest.mark.parametrize("hw", [(16, 128), (32, 128), (24, 256)])
+@pytest.mark.parametrize("hw", [(16, 128), (32, 128), (24, 256), (12, 30),
+                                (10, 36)])
 def test_fused_body_in_kernel_stencil(hw):
     """t=None folds the 5-point Dirichlet SPMV into the kernel; must match
-    the oracle that applies stencil2d_ref to Zw[:, 0]."""
+    the oracle that applies stencil2d_ref to Zw[:, 0].  Widths that are
+    not a multiple of 8 give blocks that straddle grid rows and a partial
+    last block."""
     H, W = hw
     l, n, dtype = 2, H * W, jnp.float32
     Vw, Zw, _, _, _, scalars = _fused_inputs(l, n, dtype)
@@ -202,6 +207,25 @@ def test_fused_body_in_kernel_stencil(hw):
             continue
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), atol=2e-4)
+
+
+@pytest.mark.parametrize("l", [1, 3])
+def test_fused_body_partial_last_block(l):
+    """An n with no 8-aligned divisor runs a partial last block whose
+    out-of-range rows stay out of the payload dots."""
+    n, dtype = 1001, jnp.float32
+    Vw, Zw, _, t, _, scalars = _fused_inputs(l, n, dtype)
+    scal = _pack_scal(True, scalars, l, dtype)
+    got = fused_body(Vw, Zw, scal, None, t, None, l=l, bn=256,
+                     interpret=True)
+    want = ref.fused_body_ref(Vw, Zw, None, t, None, l=l,
+                              steady=jnp.bool_(True), **scalars)
+    for lab, a, b in zip(("Vw2", "Zw2", "Zhw2", "dots"), got, want):
+        if a is None and b is None:
+            continue
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), atol=2e-4,
+                                   err_msg=lab)
 
 
 @pytest.mark.parametrize("mode", ["scalar", "vector"])

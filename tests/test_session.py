@@ -107,6 +107,30 @@ def test_prepared_batched_compiles_once(poisson):
     assert rb.converged
 
 
+@pytest.mark.parametrize("nrhs", [0, 3])
+def test_lower_is_the_program_solve_runs(poisson, nrhs):
+    """Solver.lower(b) gives the compiled program of the solve that ran:
+    after the solve, compiling it adds no jit-cache entry, and the
+    batched form is the program a pool flush runs."""
+    A, b = poisson
+    bb = b if nrhs == 0 else _batch(A, nrhs)
+    solver = Solver(A, "plcg_scan", backend="auto", **KW)
+    r = solver(bb)
+    assert r.info["backend"] == "ref"        # "auto" resolved off the chip
+    before = solver.compile_counts()
+    compiled = solver.lower(bb).compile()
+    assert solver.compile_counts() == before
+    assert compiled.memory_analysis() is not None
+    assert "while" in compiled.as_text()      # the scan of the sweep
+
+
+def test_lower_rejects_mesh_sessions(poisson, mesh11):
+    A, _ = poisson
+    solver = Solver(A, "plcg_scan", mesh=mesh11, **KW)
+    with pytest.raises(NotImplementedError):
+        solver.lower(np.zeros((20, 20)))
+
+
 def test_tol_override_prepares_new_sweep(poisson):
     """A per-call tol override keys an additional prepared sweep; the
     session default stays live alongside it."""

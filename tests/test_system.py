@@ -83,6 +83,66 @@ def test_input_specs_cover_all_cells():
     assert ok_cells == 32          # 8 long_500k cells skipped by design
 
 
+@pytest.mark.parametrize("kind,known", [("TPU v5 lite", True),
+                                         ("cpu", False), ("TPU v9", False)])
+def test_peaks_are_keyed_by_device_kind(kind, known):
+    """Roofline peaks come from a sourced per-kind table; an unknown
+    device kind is an error, never the v5e default."""
+    from repro.launch.peaks import peaks
+    if known:
+        assert peaks(kind)["hbm_bytes_s"] == 819e9
+    else:
+        with pytest.raises(KeyError, match="no published peaks"):
+            peaks(kind)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, from_env):
+    """The entry points' compile cache is $JAX_COMPILATION_CACHE_DIR when
+    set -- entries land there and nowhere else -- else the fixed
+    <repo>/.jax_cache; importing the library leaves it off."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    from repro.launch.cache import DEFAULT_CACHE_DIR
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(repo, "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cc")
+    code = textwrap.dedent(f"""
+        import os, jax, jax.numpy as jnp
+        import repro.core
+        assert (jax.config.jax_compilation_cache_dir
+                == os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+        from repro.launch.cache import enable_compile_cache
+        path = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        if {from_env}:
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+            jax.jit(lambda x: jnp.sin(x) * 3.0)(jnp.ones(7)).block_until_ready()
+        print(path)
+    """)
+    before = sorted(DEFAULT_CACHE_DIR.glob("*")) if \
+        DEFAULT_CACHE_DIR.exists() else None
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    path = out.stdout.strip().splitlines()[-1]
+    if from_env:
+        assert path == str(tmp_path / "cc")
+        assert any((tmp_path / "cc").iterdir())
+        after = sorted(DEFAULT_CACHE_DIR.glob("*")) if \
+            DEFAULT_CACHE_DIR.exists() else None
+        assert after == before
+    else:
+        assert path == str(DEFAULT_CACHE_DIR)
+        assert str(DEFAULT_CACHE_DIR.parent) == repo
+
+
 def test_solver_config_registry():
     from repro.configs import ARCHS, get_config, get_reduced
     assert len(ARCHS) == 10
