@@ -38,13 +38,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import weakref
 from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import solver_cache
+from . import solver_cache, telemetry
 from .cg import classic_cg
 from .dlanczos import d_lanczos
 from .linop import LinearOperator, dense_operator, is_bindable
@@ -52,7 +53,7 @@ from .pcg import ghysels_pcg
 from .plcg import plcg
 from .precision import as_precision_policy
 from .precond import as_preconditioner
-from .plcg_scan import plcg_solve, resolve_backend
+from .plcg_scan import plcg_solve, read_batched, resolve_backend
 from .plcg_scan import plcg_scan as _plcg_scan_engine
 from .plminres import plminres
 from .results import SolveResult
@@ -667,15 +668,21 @@ def solve(
     themselves and skip the per-call setup entirely.
     """
     from .session import Solver
-    # validate options before the keyword passthrough: session-only
-    # constructor keywords (n=) must not absorb a same-named unknown
-    # option key and dodge the uniform rejection
-    _prepare_options(get_method(method), options)
-    return Solver(A, method=method, tol=tol, maxiter=maxiter, M=M, l=l,
-                  sigma=sigma, spectrum=spectrum, backend=backend,
-                  mesh=mesh, comm=comm, restart=restart,
-                  residual_replacement=residual_replacement,
-                  precision=precision, **options).solve(b, x0=x0)
+    with telemetry.span("solver.solve") as root:
+        with telemetry.span("plcg.prepare"):
+            # validate options before the keyword passthrough: session-only
+            # constructor keywords (n=) must not absorb a same-named
+            # unknown option key and dodge the uniform rejection
+            _prepare_options(get_method(method), options)
+            solver = Solver(A, method=method, tol=tol, maxiter=maxiter, M=M,
+                            l=l, sigma=sigma, spectrum=spectrum,
+                            backend=backend, mesh=mesh, comm=comm,
+                            restart=restart,
+                            residual_replacement=residual_replacement,
+                            precision=precision, **options)
+        r = solver._solve(b, x0=x0)
+        root.requests = telemetry.request_ids(r.info.get("nrhs", 1))
+    return r
 
 
 # --------------------------------------------------------------------------
@@ -685,7 +692,7 @@ def solve(
 def _solve_batched(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
                    maxiter, M, l, sigma, spectrum, backend,
                    restart=None, rr_period=None, precision=None,
-                   get_engine=None, **options) -> SolveResult:
+                   get_engine=None, lanes=None, **options) -> SolveResult:
     nrhs = B.shape[0]
     if spec.batched == "vmap":
         return _solve_batched_vmap(spec, A, B, x0=x0, tol=tol,
@@ -693,7 +700,8 @@ def _solve_batched(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
                                    spectrum=spectrum, backend=backend,
                                    restart=restart, rr_period=rr_period,
                                    precision=precision,
-                                   get_engine=get_engine, **options)
+                                   get_engine=get_engine, lanes=lanes,
+                                   **options)
     outs = [
         spec.fn(A, B[j], None if x0 is None else x0[j], tol=tol,
                 maxiter=maxiter, M=M, l=l, sigma=sigma, spectrum=spectrum,
@@ -786,6 +794,10 @@ def _batched_engine(method_name: str, matvec, l: int, iters: int, sigma,
         build)
 
 
+#: Batched engines that have warned about their ``tol`` (once each).
+_TOL_WARNED = weakref.WeakSet()
+
+
 def _batched_program(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
                      maxiter, M, l, sigma, spectrum, backend,
                      restart=None, rr_period=None, precision=None,
@@ -806,22 +818,6 @@ def _batched_program(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
     sig = tuple(_resolve_sigma(sigma, spectrum, l))
     Bj = jnp.asarray(B)
     precision = as_precision_policy(precision)
-    # the attainable floor is set by the *compute* dtype of the scalar
-    # recurrences and convergence tests, not the storage dtype of b: a
-    # bf16-storage policy over an f32 problem still converges on f32
-    # scalars, and must not spuriously warn at tolerances those reach
-    cdt = precision.compute_dtype(Bj.dtype)
-    if tol and tol < 100 * jnp.finfo(cdt).eps:
-        import warnings
-
-        # attribute the warning to the caller of solve(), not to a frame
-        # inside this module: count the contiguous run of engine frames
-        # above us instead of hard-coding the internal call-chain depth
-        warnings.warn(
-            f"tol={tol:g} is below ~100*eps of the batched engine compute "
-            f"dtype {cdt}; lanes will hit maxiter instead of converging -- "
-            "enable jax_enable_x64 or relax tol",
-            stacklevel=_stacklevel_outside_engine())
     X0 = jnp.zeros_like(Bj) if x0 is None else jnp.asarray(x0)
     from .plcg_scan import stab_iter_slack
     stab = restart is not None or rr_period is not None
@@ -836,6 +832,24 @@ def _batched_program(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
                getattr(A, "stencil2d", None), restart, rr_period,
                ritz_refresh, maxiter if stab else None, precision, bind)
     args = (A.context, Bj, X0) if bind else (Bj, X0)
+    # below the modeled residual-gap floor of the depth-l pipeline in its
+    # storage dtype the lanes still converge on their recursive residual,
+    # but the true residual b - A x misses tol
+    from .autotune import attainable_floor
+    sdt, _ = precision.resolve(Bj.dtype)
+    floor = attainable_floor(l, sdt)
+    if tol and tol < floor and fn not in _TOL_WARNED:
+        import warnings
+        _TOL_WARNED.add(fn)           # once per built engine
+        # attribute the warning to the caller of solve(), not to a frame
+        # inside this module: count the contiguous run of engine frames
+        # above us instead of hard-coding the internal call-chain depth
+        warnings.warn(
+            f"tol={tol:g} is below the attainable floor {floor:.2g} of a "
+            f"depth-{l} pipeline with {sdt} storage; lanes may report "
+            "convergence while the true residual misses tol -- enable "
+            "jax_enable_x64 or relax tol",
+            stacklevel=_stacklevel_outside_engine())
     return fn, args, sig, stab
 
 
@@ -844,7 +858,8 @@ def _solve_batched_vmap(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
                         restart=None, rr_period=None, precision=None,
                         exploit_symmetry: bool = True, unroll: int = 1,
                         ritz_refresh: bool = True,
-                        get_engine=None, **options) -> SolveResult:
+                        get_engine=None, lanes=None,
+                        **options) -> SolveResult:
     """One jitted ``vmap`` of the scan engine over the stacked RHS.
 
     A single XLA compilation covers all ``nrhs`` systems; converged lanes
@@ -858,36 +873,23 @@ def _solve_batched_vmap(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
     ``get_engine`` (internal) lets a prepared :class:`session.Solver`
     inject its strongly-held jitted engine in place of the weak-key cache
     lookup; it receives exactly :func:`_batched_engine`'s arguments.
+    Lanes past the first ``lanes`` (default: none) are padding: they
+    count in the ``bodies`` telemetry counter, not in ``useful``.
     """
-    fn, args, sig, stab = _batched_program(
-        spec, A, B, x0=x0, tol=tol, maxiter=maxiter, M=M, l=l, sigma=sigma,
-        spectrum=spectrum, backend=backend, restart=restart,
-        rr_period=rr_period, precision=precision,
-        exploit_symmetry=exploit_symmetry, unroll=unroll,
-        ritz_refresh=ritz_refresh, get_engine=get_engine, **options)
-    precision = as_precision_policy(precision)
-    out = fn(*args)
-    resn = np.asarray(out.resnorms)                     # (nrhs, iters)
-    conv = np.asarray(out.converged)
-    brk = np.asarray(out.breakdown)
-    k_done = np.asarray(out.k_done)
-    if stab:
-        # restart / replacement dead bodies interleave with committed
-        # updates, so the in-order residual history is the committed mask
-        # (not a contiguous count slice)
-        committed = np.asarray(out.committed, dtype=bool)
-        resnorms = [[float(r) for r in row[m]]
-                    for row, m in zip(resn, committed)]
-        restarts_pl = np.asarray(out.restarts)
-        repl_pl = np.asarray(out.replacements)
-    else:
-        # lane j commits |zeta_k| for k = 0..k_done[j] at trace indices
-        # l..l+k_done[j]; slicing by count (not value-filtering) keeps a
-        # legitimate exact-zero residual in the trace
-        resnorms = [[float(r) for r in row[l: l + int(k) + 1]]
-                    for row, k in zip(resn, k_done)]
-        restarts_pl = np.zeros(conv.shape[0], dtype=int)
-        repl_pl = np.zeros(conv.shape[0], dtype=int)
+    with telemetry.span("plcg.prepare"):
+        fn, args, sig, stab = _batched_program(
+            spec, A, B, x0=x0, tol=tol, maxiter=maxiter, M=M, l=l,
+            sigma=sigma, spectrum=spectrum, backend=backend,
+            restart=restart, rr_period=rr_period, precision=precision,
+            exploit_symmetry=exploit_symmetry, unroll=unroll,
+            ritz_refresh=ritz_refresh, get_engine=get_engine, **options)
+        precision = as_precision_policy(precision)
+    out = telemetry.dispatch(fn, *args)
+    telemetry.wait(out)
+    (resnorms, conv, brk, k_done, restarts_pl, repl_pl) = read_batched(
+        (out.resnorms, out.converged, out.breakdown, out.k_done,
+         out.committed, out.restarts, out.replacements, out.trips),
+        l=l, stab=stab, lanes=lanes)
     return SolveResult(
         x=out.x,
         resnorms=resnorms,

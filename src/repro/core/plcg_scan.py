@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import telemetry
 from .precision import as_precision_policy
 from .solver_cache import WeakCallableCache, weakly_callable
 from .solver_cache import clear_solver_cache  # noqa: F401  (re-export)
@@ -87,6 +88,8 @@ class PLCGOut(NamedTuple):
     #                        order; robust to restarts scattering the rows)
     restarts: jax.Array    # in-scan restarts taken (0 on the legacy path)
     replacements: jax.Array  # residual replacements taken
+    trips: jax.Array       # int32 scan bodies this sweep ran (its trip
+    #                        count; the scan's static length)
 
 
 def _default_dot(a, b):
@@ -488,114 +491,127 @@ def plcg_scan(
         1) -- after which the lane is bit-for-bit a fresh solve started
         at x, sharing every collective with its still-active neighbors.
         """
-        inflight2 = queue_push(st.inflight, payload, q_aux)
-        # NaN/Inf-safe breakdown: a non-finite zeta fails BOTH the old
-        # convergence and breakdown predicates, silently spending the
-        # whole budget -- treat it as a breakdown of this body
-        brk2 = brk | ((ph >= l) & jnp.logical_not(jnp.isfinite(zeta2)))
-        if stab:
-            active = (st.wait == 0) & jnp.logical_not(st.done)
-        else:
-            active = jnp.logical_not(st.done)
-        commit = active & jnp.logical_not(brk2)
-        conv_now = commit & (ph >= l) & (jnp.abs(zeta2) <= tol * bnorm)
-        # budget freeze: k2 + 1 updates are committed after this body
-        spent = (jnp.asarray(False) if k_budget is None
-                 else k2 + 1 >= k_budget)
-        if stab:
-            can_restart = st.restarts < restart_cap
-            want_restart = brk2 & active & can_restart & ~spent
-            committed_update = commit & (ph >= l)
-            rr_due = (committed_update & (st.since_rr + 1 >= rp)
-                      & ~conv_now & ~spent) if rp > 0 else jnp.asarray(False)
-            schedule = want_restart | rr_due
-            # the seed body's re-seeded residual norm doubles as a
-            # convergence / hard-failure probe: beta == 0 at tolerance
-            # means x is (numerically) exact, non-finite beta means the
-            # lane is unrecoverable
-            seed_conv = (seed_now & jnp.isfinite(beta2)
-                         & (jnp.sqrt(jnp.maximum(beta2, 0.0)) <= tol * bnorm))
-            seed_fail = seed_now & ~seed_ok & ~seed_conv
-            brk_term = brk2 & active & ~want_restart
-            conv_now = conv_now | seed_conv
-        else:
-            want_restart = rr_due = seed_fail = jnp.asarray(False)
-            brk_term = brk2 & active
-            committed_update = commit & (ph >= l)
-        done_o = st.done | brk_term | conv_now | (spent & active) | seed_fail
-        converged_o = st.converged | conv_now
-        breakdown_o = st.breakdown | brk_term | seed_fail
-        new = PLCGState(
-            Zw=Zw2, Vw=Vw2, Zhw=Zhw2, Gb=Gb2, gam=gam2, dlt=dlt2,
-            inflight=inflight2, x=x2, p=p2, eta=eta2, zeta=zeta2,
-            k_done=k2, done=done_o, converged=converged_o,
-            breakdown=breakdown_o,
-            # stab fields pass through the commit select untouched (same
-            # value on both sides); their real updates are overlaid below
-            ph=st.ph, wait=st.wait, beta=st.beta, sig_c=st.sig_c,
-            restarts=st.restarts, repl=st.repl, since_rr=st.since_rr,
-        )
-        out = jax.tree.map(
-            lambda a_new, a_old: jnp.where(commit, a_new, a_old), new,
-            st._replace(done=done_o, converged=converged_o,
-                        breakdown=breakdown_o))
-        if stab:
-            reseed_or_seed = reseed_now | seed_now
-            zcol = jnp.zeros(ncols, cdt)
-            out = out._replace(
-                # re-seeding lanes bypass the commit mask: the stashed /
-                # seeded windows (already selected in the body) land, the
-                # banded G and the recurrences reset to the init state
-                Zw=jnp.where(reseed_or_seed, Zw2, out.Zw),
-                Vw=jnp.where(reseed_or_seed, Vw2, out.Vw),
-                Zhw=(jnp.where(reseed_or_seed, Zhw2, out.Zhw)
-                     if prec is not None else out.Zhw),
-                Gb=jnp.where(reseed_now, Gb0, out.Gb),
-                gam=jnp.where(reseed_now, zcol, out.gam),
-                dlt=jnp.where(reseed_now, zcol, out.dlt),
-                p=jnp.where(reseed_now, jnp.zeros_like(st.p), out.p),
-                eta=jnp.where(reseed_now, 0.0, out.eta),
-                zeta=jnp.where(reseed_now, 0.0, out.zeta),
-                # the queue ALWAYS shifts: the re-seed reduction must
-                # transit it, and frozen lanes only ever push into it
-                inflight=inflight2,
-                wait=jnp.where(reseed_now, l,
-                               jnp.where(seed_now, 0,
-                                         jnp.where(st.wait > 1, st.wait - 1,
-                                                   jnp.where(schedule, l + 1,
-                                                             0)))
-                               ).astype(st.wait.dtype),
-                # the seed body IS body 0 of the new phase
-                ph=jnp.where(seed_now, 1,
-                             jnp.where(commit, ph + 1, ph)
-                             ).astype(st.ph.dtype),
-                beta=jnp.where(seed_now, beta_new, st.beta),
-                restarts=st.restarts + want_restart.astype(st.restarts.dtype),
-                repl=st.repl + rr_due.astype(st.repl.dtype),
-                since_rr=jnp.where(seed_now, 0,
-                                   st.since_rr
-                                   + committed_update.astype(st.since_rr.dtype)
-                                   ).astype(st.since_rr.dtype),
+        with jax.named_scope("plcg.reduce"):
+            inflight2 = queue_push(st.inflight, payload, q_aux)
+        with jax.named_scope("plcg.update"):
+            # NaN/Inf-safe breakdown: a non-finite zeta fails BOTH the old
+            # convergence and breakdown predicates, silently spending the
+            # whole budget -- treat it as a breakdown of this body
+            brk2 = brk | ((ph >= l) & jnp.logical_not(jnp.isfinite(zeta2)))
+            if stab:
+                active = (st.wait == 0) & jnp.logical_not(st.done)
+            else:
+                active = jnp.logical_not(st.done)
+            commit = active & jnp.logical_not(brk2)
+            conv_now = commit & (ph >= l) & (jnp.abs(zeta2) <= tol * bnorm)
+            # budget freeze: k2 + 1 updates are committed after this body
+            spent = (jnp.asarray(False) if k_budget is None
+                     else k2 + 1 >= k_budget)
+            if stab:
+                can_restart = st.restarts < restart_cap
+                want_restart = brk2 & active & can_restart & ~spent
+                committed_update = commit & (ph >= l)
+                rr_due = ((committed_update & (st.since_rr + 1 >= rp)
+                           & ~conv_now & ~spent) if rp > 0
+                          else jnp.asarray(False))
+                schedule = want_restart | rr_due
+                # the seed body's re-seeded residual norm doubles as a
+                # convergence / hard-failure probe: beta == 0 at tolerance
+                # means x is (numerically) exact, non-finite beta means the
+                # lane is unrecoverable
+                seed_conv = (seed_now & jnp.isfinite(beta2)
+                             & (jnp.sqrt(jnp.maximum(beta2, 0.0))
+                                <= tol * bnorm))
+                seed_fail = seed_now & ~seed_ok & ~seed_conv
+                brk_term = brk2 & active & ~want_restart
+                conv_now = conv_now | seed_conv
+            else:
+                want_restart = rr_due = seed_fail = jnp.asarray(False)
+                brk_term = brk2 & active
+                committed_update = commit & (ph >= l)
+            done_o = st.done | brk_term | conv_now | (spent & active) | seed_fail
+            converged_o = st.converged | conv_now
+            breakdown_o = st.breakdown | brk_term | seed_fail
+            new = PLCGState(
+                Zw=Zw2, Vw=Vw2, Zhw=Zhw2, Gb=Gb2, gam=gam2, dlt=dlt2,
+                inflight=inflight2, x=x2, p=p2, eta=eta2, zeta=zeta2,
+                k_done=k2, done=done_o, converged=converged_o,
+                breakdown=breakdown_o,
+                # stab fields pass through the commit select untouched
+                # (same value on both sides); their real updates are
+                # overlaid below
+                ph=st.ph, wait=st.wait, beta=st.beta, sig_c=st.sig_c,
+                restarts=st.restarts, repl=st.repl, since_rr=st.since_rr,
             )
-            if use_ritz:
-                # Ritz-refresh the shifts from the tail of the COMMITTED
-                # tridiagonal of the phase that just ended (harvested at
-                # the reseed body, before gamma/delta reset): Leja-ordered
-                # eigenvalues of the MR x MR trailing block (Remark 3)
-                from .shifts import leja_order, ritz_values_from_tridiag
-                MR = min(max(4, 2 * l), ncols)
-                m = ph - l                    # committed columns this phase
-                lo = jnp.clip(m - MR, 0, ncols - MR)
-                gw = jax.lax.dynamic_slice_in_dim(st.gam, lo, MR)
-                dw = jax.lax.dynamic_slice_in_dim(st.dlt, lo, MR)
-                okr = (reseed_now & (m >= MR)
-                       & jnp.all(jnp.isfinite(gw)) & jnp.all(jnp.isfinite(dw)))
-                gw = jnp.where(okr, gw, 1.0)   # sanitized -> T = I
-                dw = jnp.where(okr, dw, 0.0)
-                sig_new = leja_order(ritz_values_from_tridiag(gw, dw), l)
+            out = jax.tree.map(
+                lambda a_new, a_old: jnp.where(commit, a_new, a_old), new,
+                st._replace(done=done_o, converged=converged_o,
+                            breakdown=breakdown_o))
+        if stab:
+            with jax.named_scope("plcg.stab"):
+                reseed_or_seed = reseed_now | seed_now
+                zcol = jnp.zeros(ncols, cdt)
                 out = out._replace(
-                    sig_c=jnp.where(okr, sig_new.astype(cdt), st.sig_c))
-        res = jnp.where(committed_update, jnp.abs(zeta2), 0.0)
+                    # re-seeding lanes bypass the commit mask: the stashed
+                    # / seeded windows (already selected in the body) land,
+                    # the banded G and the recurrences reset to the init
+                    # state
+                    Zw=jnp.where(reseed_or_seed, Zw2, out.Zw),
+                    Vw=jnp.where(reseed_or_seed, Vw2, out.Vw),
+                    Zhw=(jnp.where(reseed_or_seed, Zhw2, out.Zhw)
+                         if prec is not None else out.Zhw),
+                    Gb=jnp.where(reseed_now, Gb0, out.Gb),
+                    gam=jnp.where(reseed_now, zcol, out.gam),
+                    dlt=jnp.where(reseed_now, zcol, out.dlt),
+                    p=jnp.where(reseed_now, jnp.zeros_like(st.p), out.p),
+                    eta=jnp.where(reseed_now, 0.0, out.eta),
+                    zeta=jnp.where(reseed_now, 0.0, out.zeta),
+                    # the queue ALWAYS shifts: the re-seed reduction must
+                    # transit it, and frozen lanes only ever push into it
+                    inflight=inflight2,
+                    wait=jnp.where(reseed_now, l,
+                                   jnp.where(seed_now, 0,
+                                             jnp.where(st.wait > 1,
+                                                       st.wait - 1,
+                                                       jnp.where(schedule,
+                                                                 l + 1, 0)))
+                                   ).astype(st.wait.dtype),
+                    # the seed body IS body 0 of the new phase
+                    ph=jnp.where(seed_now, 1,
+                                 jnp.where(commit, ph + 1, ph)
+                                 ).astype(st.ph.dtype),
+                    beta=jnp.where(seed_now, beta_new, st.beta),
+                    restarts=(st.restarts
+                              + want_restart.astype(st.restarts.dtype)),
+                    repl=st.repl + rr_due.astype(st.repl.dtype),
+                    since_rr=jnp.where(seed_now, 0,
+                                       st.since_rr
+                                       + committed_update.astype(
+                                           st.since_rr.dtype)
+                                       ).astype(st.since_rr.dtype),
+                )
+                if use_ritz:
+                    # Ritz-refresh the shifts from the tail of the
+                    # COMMITTED tridiagonal of the phase that just ended
+                    # (harvested at the reseed body, before gamma/delta
+                    # reset): Leja-ordered eigenvalues of the MR x MR
+                    # trailing block (Remark 3)
+                    from .shifts import leja_order, ritz_values_from_tridiag
+                    MR = min(max(4, 2 * l), ncols)
+                    m = ph - l                # committed columns this phase
+                    lo = jnp.clip(m - MR, 0, ncols - MR)
+                    gw = jax.lax.dynamic_slice_in_dim(st.gam, lo, MR)
+                    dw = jax.lax.dynamic_slice_in_dim(st.dlt, lo, MR)
+                    okr = (reseed_now & (m >= MR)
+                           & jnp.all(jnp.isfinite(gw))
+                           & jnp.all(jnp.isfinite(dw)))
+                    gw = jnp.where(okr, gw, 1.0)   # sanitized -> T = I
+                    dw = jnp.where(okr, dw, 0.0)
+                    sig_new = leja_order(ritz_values_from_tridiag(gw, dw), l)
+                    out = out._replace(
+                        sig_c=jnp.where(okr, sig_new.astype(cdt), st.sig_c))
+        with jax.named_scope("plcg.update"):
+            res = jnp.where(committed_update, jnp.abs(zeta2), 0.0)
         return out, (res, committed_update)
 
     def stab_ctx(st: PLCGState, i):
@@ -605,9 +621,10 @@ def plcg_scan(
         if not stab:
             return (i, jnp.asarray(False), jnp.asarray(False), st.Zw[:, 0],
                     sig)
-        reseed_now = st.wait == l + 1
-        seed_now = st.wait == 1
-        spmv_in = jnp.where(reseed_now, st.x, st.Zw[:, 0])
+        with jax.named_scope("plcg.stab"):
+            reseed_now = st.wait == l + 1
+            seed_now = st.wait == 1
+            spmv_in = jnp.where(reseed_now, st.x, st.Zw[:, 0])
         sig_arr = st.sig_c if use_ritz else sig
         return st.ph, reseed_now, seed_now, spmv_in, sig_arr
 
@@ -660,52 +677,64 @@ def plcg_scan(
                 (Vw_sd, Zw_sd, Zhw_sd), (Vw_st, Zw_st, Zhw_st))
 
     def body(st: PLCGState, i):
+        # each phase runs under a named scope, so every HLO op of the body
+        # carries its phase in its op_name metadata (plcg.spmv, .reduce,
+        # .scalars, .recur, .dots, .update, .stab)
         ph, reseed_now, seed_now, spmv_in, sig_arr = stab_ctx(st, i)
         # ---------------- (K1) SPMV --------------------------------------
         # SPMV arithmetic runs in the compute dtype (on a mesh this keeps
         # halo-exchange payloads cdt); the resulting t / t_hat STREAMS
         # are storage-dtype, rounded once -- exactly what the fused
         # megakernel tier stores.  Identity casts under the default policy.
-        t_hat = matvec(spmv_in.astype(cdt)).astype(sdt)
-        t = prec(t_hat).astype(sdt) if prec is not None else t_hat
+        with jax.named_scope("plcg.spmv"):
+            t_hat = matvec(spmv_in.astype(cdt)).astype(sdt)
+            t = prec(t_hat).astype(sdt) if prec is not None else t_hat
         # pop AFTER the SPMV + shard-local preconditioner apply in trace
         # order: with a split comm policy the head-of-queue gather is
         # issued here with no data dependence on t, so the prec apply is
         # free to overlap the in-flight reduction (paper Remark 13)
-        col_in, q_aux = queue_pop(st.inflight)
+        with jax.named_scope("plcg.reduce"):
+            col_in, q_aux = queue_pop(st.inflight)
         col_in_full, col_in = col_in, (col_in[:W] if stab else col_in)
 
         c = ph - l + 1                      # column being finalized
 
         def warmup(_):
-            s = sig_arr[jnp.minimum(ph, l - 1)]
-            znew = t - s * st.Zw[:, 0]
-            zhnew = (t_hat - s * st.Zhw[:, 0]) if prec is not None else None
+            with jax.named_scope("plcg.recur"):
+                s = sig_arr[jnp.minimum(ph, l - 1)]
+                znew = t - s * st.Zw[:, 0]
+                zhnew = ((t_hat - s * st.Zhw[:, 0]) if prec is not None
+                         else None)
             return (st.Vw, st.Gb, st.gam, st.dlt, znew, zhnew,
                     jnp.asarray(False), st.x, st.p, st.eta, st.zeta,
                     st.k_done)
 
         def steady(_):
-            (col, gcc, brk, Gb2, gam2, dlt2, gam_c1, dlt_c1,
-             dsub) = scalar_block(st, ph, c, col_in, sig_arr)
-            # -------- (K4) v recurrence (line 17) -------------------------
-            # v_c = (z_c - sum_k col[k] v_{c-2l+k}) / gcc ;
-            # v_{c-2l+k} = Vw[:, 2l-1-k]
-            if use_kernels:
-                vnew = _waxpy(st.Vw[:, :2 * l], st.Zw[:, l - 1],
-                              col[:2 * l][::-1], gcc)
-            else:
-                vsum = st.Vw[:, :2 * l] @ col[:2 * l][::-1]
-                vnew = (st.Zw[:, l - 1] - vsum) / gcc
-            Vw2 = jnp.concatenate([vnew.astype(sdt)[:, None],
-                                   st.Vw[:, :-1]], axis=1)
-            # -------- (K4) z recurrence (line 18) -------------------------
-            znew = (t - gam_c1 * st.Zw[:, 0] - dsub * st.Zw[:, 1]) / dlt_c1
-            zhnew = ((t_hat - gam_c1 * st.Zhw[:, 0] - dsub * st.Zhw[:, 1])
-                     / dlt_c1 if prec is not None else None)
+            with jax.named_scope("plcg.scalars"):
+                (col, gcc, brk, Gb2, gam2, dlt2, gam_c1, dlt_c1,
+                 dsub) = scalar_block(st, ph, c, col_in, sig_arr)
+            with jax.named_scope("plcg.recur"):
+                # -------- (K4) v recurrence (line 17) ---------------------
+                # v_c = (z_c - sum_k col[k] v_{c-2l+k}) / gcc ;
+                # v_{c-2l+k} = Vw[:, 2l-1-k]
+                if use_kernels:
+                    vnew = _waxpy(st.Vw[:, :2 * l], st.Zw[:, l - 1],
+                                  col[:2 * l][::-1], gcc)
+                else:
+                    vsum = st.Vw[:, :2 * l] @ col[:2 * l][::-1]
+                    vnew = (st.Zw[:, l - 1] - vsum) / gcc
+                Vw2 = jnp.concatenate([vnew.astype(sdt)[:, None],
+                                       st.Vw[:, :-1]], axis=1)
+                # -------- (K4) z recurrence (line 18) ---------------------
+                znew = ((t - gam_c1 * st.Zw[:, 0] - dsub * st.Zw[:, 1])
+                        / dlt_c1)
+                zhnew = ((t_hat - gam_c1 * st.Zhw[:, 0]
+                          - dsub * st.Zhw[:, 1]) / dlt_c1
+                         if prec is not None else None)
             # -------- (K6) solution update (lines 22-31) ------------------
-            x2, p2, eta_k, zeta_k, k2 = solution_update(st, ph, gam2,
-                                                        Vw2[:, 1])
+            with jax.named_scope("plcg.update"):
+                x2, p2, eta_k, zeta_k, k2 = solution_update(st, ph, gam2,
+                                                            Vw2[:, 1])
             return (Vw2, Gb2, gam2, dlt2, znew, zhnew, brk,
                     x2, p2, eta_k, zeta_k, k2)
 
@@ -716,62 +745,69 @@ def plcg_scan(
         # AXPYs so evaluating it alongside steady costs nothing, and the
         # discarded branch's values (incl. div-by-zero garbage during the
         # first l iterations) are dropped by the select
-        (Vw2, Gb2, gam2, dlt2, znew, zhnew, brk, x2, p2, eta2, zeta2,
-         k2) = jax.tree.map(
-            functools.partial(jnp.where, ph >= l), steady(None), warmup(None))
+        stdy, warm = steady(None), warmup(None)
+        with jax.named_scope("plcg.recur"):
+            (Vw2, Gb2, gam2, dlt2, znew, zhnew, brk, x2, p2, eta2, zeta2,
+             k2) = jax.tree.map(
+                functools.partial(jnp.where, ph >= l), stdy, warm)
 
-        Zw2 = jnp.concatenate([znew.astype(sdt)[:, None],
-                               st.Zw[:, :-1]], axis=1)
-        Zhw2 = (jnp.concatenate([zhnew.astype(sdt)[:, None],
-                                 st.Zhw[:, :-1]], axis=1)
-                if prec is not None else st.Zhw)
+            Zw2 = jnp.concatenate([znew.astype(sdt)[:, None],
+                                   st.Zw[:, :-1]], axis=1)
+            Zhw2 = (jnp.concatenate([zhnew.astype(sdt)[:, None],
+                                     st.Zhw[:, :-1]], axis=1)
+                    if prec is not None else st.Zhw)
         # payload dots consume the pre-rounding compute-dtype lhs; only
         # the stored window is quantized to sdt
         lhs = zhnew if prec is not None else znew
         seed_kw = {}
         ph_pay = ph
         if stab:
-            (slotW, beta2, seed_ok, beta_new, sel3, seeded,
-             stash) = stab_seed(st, t, t_hat, col_in_full, reseed_now,
-                                seed_now, sig_arr)
-            # window selection BEFORE the payload dots so re-seeding lanes
-            # push dots of the stashed/seeded windows through the shared
-            # reduction (the seed body's payload IS fresh body 0's)
-            Vw2 = sel3(seeded[0], stash[0], Vw2)
-            Zw2 = sel3(seeded[1], stash[1], Zw2)
-            if prec is not None:
-                Zhw2 = sel3(seeded[2], stash[2], Zhw2)
-            lhs = (Zhw2[:, 0] if prec is not None else Zw2[:, 0]).astype(cdt)
-            ph_pay = jnp.where(seed_now, 0, ph)
+            with jax.named_scope("plcg.stab"):
+                (slotW, beta2, seed_ok, beta_new, sel3, seeded,
+                 stash) = stab_seed(st, t, t_hat, col_in_full, reseed_now,
+                                    seed_now, sig_arr)
+                # window selection BEFORE the payload dots so re-seeding
+                # lanes push dots of the stashed/seeded windows through the
+                # shared reduction (the seed body's payload IS fresh body
+                # 0's)
+                Vw2 = sel3(seeded[0], stash[0], Vw2)
+                Zw2 = sel3(seeded[1], stash[1], Zw2)
+                if prec is not None:
+                    Zhw2 = sel3(seeded[2], stash[2], Zhw2)
+                lhs = (Zhw2[:, 0] if prec is not None
+                       else Zw2[:, 0]).astype(cdt)
+                ph_pay = jnp.where(seed_now, 0, ph)
             seed_kw = dict(reseed_now=reseed_now, seed_now=seed_now,
                            beta_new=beta_new, seed_ok=seed_ok, beta2=beta2)
         # ---------------- (K5) dot-product payload for column i+1 --------
-        if exploit_symmetry:
-            def vdots_full(_):
-                if use_kernels:
-                    return _mdot(Vw2[:, :l + 1], lhs)
-                return lhs @ Vw2[:, :l + 1]
+        with jax.named_scope("plcg.dots"):
+            if exploit_symmetry:
+                def vdots_full(_):
+                    if use_kernels:
+                        return _mdot(Vw2[:, :l + 1], lhs)
+                    return lhs @ Vw2[:, :l + 1]
 
-            def vdots_one(_):
-                out = jnp.zeros(l + 1, cdt)
-                return out.at[0].set(dot(Vw2[:, 0], lhs).astype(cdt))
+                def vdots_one(_):
+                    out = jnp.zeros(l + 1, cdt)
+                    return out.at[0].set(dot(Vw2[:, 0], lhs).astype(cdt))
 
-            vd = jax.lax.cond(ph_pay < 2 * l - 1, vdots_full, vdots_one, None)
-        elif use_kernels:
-            vd = _mdot(Vw2[:, :l + 1], lhs)
-        else:
-            vd = jnp.stack([dot(Vw2[:, j], lhs) for j in range(l + 1)])
-        if use_kernels:
-            zd = _mdot(Zw2[:, :l], lhs)
-        else:
-            zd = jnp.stack([dot(Zw2[:, j], lhs) for j in range(l)])
-        # mask payload slots whose row index i+1-2l+k is negative (the v
-        # window is zero-initialized except v_0, which must not leak into
-        # nonexistent rows during warmup)
-        vmask = jnp.arange(l + 1) + (ph_pay + 1 - 2 * l) >= 0
-        payload = jnp.concatenate([vd[::-1] * vmask, zd[::-1]])  # band layout
-        if stab:
-            payload = jnp.concatenate([payload, slotW[None]])
+                vd = jax.lax.cond(ph_pay < 2 * l - 1, vdots_full, vdots_one,
+                                  None)
+            elif use_kernels:
+                vd = _mdot(Vw2[:, :l + 1], lhs)
+            else:
+                vd = jnp.stack([dot(Vw2[:, j], lhs) for j in range(l + 1)])
+            if use_kernels:
+                zd = _mdot(Zw2[:, :l], lhs)
+            else:
+                zd = jnp.stack([dot(Zw2[:, j], lhs) for j in range(l)])
+            # mask payload slots whose row index i+1-2l+k is negative (the
+            # v window is zero-initialized except v_0, which must not leak
+            # into nonexistent rows during warmup)
+            vmask = jnp.arange(l + 1) + (ph_pay + 1 - 2 * l) >= 0
+            payload = jnp.concatenate([vd[::-1] * vmask, zd[::-1]])  # band
+            if stab:
+                payload = jnp.concatenate([payload, slotW[None]])
         return finalize(st, ph, payload, q_aux, brk, x2, p2, eta2, zeta2, k2,
                         Vw2, Zw2, Zhw2, Gb2, gam2, dlt2, **seed_kw)
 
@@ -785,82 +821,93 @@ def plcg_scan(
         a documented small overhead of restart-enabled fused sweeps."""
         ph, reseed_now, seed_now, spmv_in, sig_arr = stab_ctx(st, i)
         c = ph - l + 1
-        col_in, q_aux = queue_pop(st.inflight)
+        with jax.named_scope("plcg.reduce"):
+            col_in, q_aux = queue_pop(st.inflight)
         col_in_full, col_in = col_in, (col_in[:W] if stab else col_in)
-        (col, gcc, brk, Gb2, gam2, dlt2, gam_c1, dlt_c1,
-         dsub) = scalar_block(st, ph, c, col_in, sig_arr)
-        if fuse_stencil:
-            # in-kernel SPMV (+ in-kernel diag apply when preconditioned)
-            t = t_hat = None
-        elif split_stencil:
-            # stencil hint without full fusion: (K1) as the Pallas stencil
-            # kernel (launch 1 of the 2-launch split), prec applied
-            # between the launches
-            H2d, W2d = stencil_hw
-            z2d = spmv_in.reshape(H2d, W2d)
-            zr = jnp.zeros_like
-            t_hat = kops.stencil2d_apply(
-                z2d, zr(z2d[0]), zr(z2d[0]), zr(z2d[:, 0]), zr(z2d[:, 0]),
-                use_pallas=True).reshape(-1)
-            t = prec(t_hat).astype(sdt) if prec is not None else t_hat
-        else:
-            # compute-dtype SPMV, storage-dtype streams (see body())
-            t_hat = matvec(spmv_in.astype(cdt)).astype(sdt)
-            if prec is None:
-                t = t_hat
-            elif fuse_diag:
-                t = None            # the kernel applies invd to t_hat
+        with jax.named_scope("plcg.scalars"):
+            (col, gcc, brk, Gb2, gam2, dlt2, gam_c1, dlt_c1,
+             dsub) = scalar_block(st, ph, c, col_in, sig_arr)
+        with jax.named_scope("plcg.spmv"):
+            if fuse_stencil:
+                # in-kernel SPMV (+ in-kernel diag apply when
+                # preconditioned)
+                t = t_hat = None
+            elif split_stencil:
+                # stencil hint without full fusion: (K1) as the Pallas
+                # stencil kernel (launch 1 of the 2-launch split), prec
+                # applied between the launches
+                H2d, W2d = stencil_hw
+                z2d = spmv_in.reshape(H2d, W2d)
+                zr = jnp.zeros_like
+                t_hat = kops.stencil2d_apply(
+                    z2d, zr(z2d[0]), zr(z2d[0]), zr(z2d[:, 0]),
+                    zr(z2d[:, 0]), use_pallas=True).reshape(-1)
+                t = prec(t_hat).astype(sdt) if prec is not None else t_hat
             else:
-                t = prec(t_hat).astype(sdt)
-        Vw2, Zw2, Zhw2k, dots = kops.fused_body_apply(
-            st.Vw, st.Zw, st.Zhw if prec is not None else None,
-            t, t_hat if prec is not None else None,
-            l=l, steady=ph >= l, s_warm=sig_arr[jnp.minimum(ph, l - 1)],
-            gam=gam_c1, dlt=dlt_c1, dsub=dsub, gcc=gcc,
-            g=col[:2 * l][::-1], invd=invd,
-            stencil_hw=stencil_hw if fuse_stencil else None,
-            use_pallas=True)
+                # compute-dtype SPMV, storage-dtype streams (see body())
+                t_hat = matvec(spmv_in.astype(cdt)).astype(sdt)
+                if prec is None:
+                    t = t_hat
+                elif fuse_diag:
+                    t = None            # the kernel applies invd to t_hat
+                else:
+                    t = prec(t_hat).astype(sdt)
+        with jax.named_scope("plcg.fused"):
+            Vw2, Zw2, Zhw2k, dots = kops.fused_body_apply(
+                st.Vw, st.Zw, st.Zhw if prec is not None else None,
+                t, t_hat if prec is not None else None,
+                l=l, steady=ph >= l,
+                s_warm=sig_arr[jnp.minimum(ph, l - 1)],
+                gam=gam_c1, dlt=dlt_c1, dsub=dsub, gcc=gcc,
+                g=col[:2 * l][::-1], invd=invd,
+                stencil_hw=stencil_hw if fuse_stencil else None,
+                use_pallas=True)
         Zhw2 = Zhw2k if prec is not None else st.Zhw
         dots = dots.astype(cdt)
         vd_full, zd = dots[:l + 1], dots[l + 1:]
-        x2, p2, eta_k, zeta_k, k2 = solution_update(st, ph, gam2, Vw2[:, 1])
-        # warmup select for the scalar state only -- the vector windows
-        # were already phase-selected inside the kernel
-        (Gb2, gam2, dlt2, brk, x2, p2, eta2, zeta2, k2) = jax.tree.map(
-            functools.partial(jnp.where, ph >= l),
-            (Gb2, gam2, dlt2, brk, x2, p2, eta_k, zeta_k, k2),
-            (st.Gb, st.gam, st.dlt, jnp.asarray(False), st.x, st.p,
-             st.eta, st.zeta, st.k_done))
+        with jax.named_scope("plcg.update"):
+            x2, p2, eta_k, zeta_k, k2 = solution_update(st, ph, gam2,
+                                                        Vw2[:, 1])
+            # warmup select for the scalar state only -- the vector windows
+            # were already phase-selected inside the kernel
+            (Gb2, gam2, dlt2, brk, x2, p2, eta2, zeta2, k2) = jax.tree.map(
+                functools.partial(jnp.where, ph >= l),
+                (Gb2, gam2, dlt2, brk, x2, p2, eta_k, zeta_k, k2),
+                (st.Gb, st.gam, st.dlt, jnp.asarray(False), st.x, st.p,
+                 st.eta, st.zeta, st.k_done))
         seed_kw = {}
         ph_pay = ph
         if stab:
-            (slotW, beta2, seed_ok, beta_new, sel3, seeded,
-             stash) = stab_seed(st, t, t_hat, col_in_full, reseed_now,
-                                seed_now, sig_arr)
-            Vw2 = sel3(seeded[0], stash[0], Vw2)
-            Zw2 = sel3(seeded[1], stash[1], Zw2)
-            if prec is not None:
-                Zhw2 = sel3(seeded[2], stash[2], Zhw2)
-            # recompute the payload from the selected windows: the
-            # in-kernel dots saw the pre-selection windows
-            lhs = (Zhw2[:, 0] if prec is not None else Zw2[:, 0]).astype(cdt)
-            vd_full = lhs @ Vw2[:, :l + 1]
-            zd = lhs @ Zw2[:, :l]
-            ph_pay = jnp.where(seed_now, 0, ph)
+            with jax.named_scope("plcg.stab"):
+                (slotW, beta2, seed_ok, beta_new, sel3, seeded,
+                 stash) = stab_seed(st, t, t_hat, col_in_full, reseed_now,
+                                    seed_now, sig_arr)
+                Vw2 = sel3(seeded[0], stash[0], Vw2)
+                Zw2 = sel3(seeded[1], stash[1], Zw2)
+                if prec is not None:
+                    Zhw2 = sel3(seeded[2], stash[2], Zhw2)
+                # recompute the payload from the selected windows: the
+                # in-kernel dots saw the pre-selection windows
+                lhs = (Zhw2[:, 0] if prec is not None
+                       else Zw2[:, 0]).astype(cdt)
+                vd_full = lhs @ Vw2[:, :l + 1]
+                zd = lhs @ Zw2[:, :l]
+                ph_pay = jnp.where(seed_now, 0, ph)
             seed_kw = dict(reseed_now=reseed_now, seed_now=seed_now,
                            beta_new=beta_new, seed_ok=seed_ok, beta2=beta2)
-        if exploit_symmetry:
-            # mirror the legacy single-dot branch: beyond the startup
-            # phase only <v_{i+1-2l}, z> is new, the rest comes from the
-            # symmetric fill of (K2)
-            vd = jnp.where(ph_pay < 2 * l - 1, vd_full,
-                           jnp.zeros_like(vd_full).at[0].set(vd_full[0]))
-        else:
-            vd = vd_full
-        vmask = jnp.arange(l + 1) + (ph_pay + 1 - 2 * l) >= 0
-        payload = jnp.concatenate([vd[::-1] * vmask, zd[::-1]])
-        if stab:
-            payload = jnp.concatenate([payload, slotW[None]])
+        with jax.named_scope("plcg.dots"):
+            if exploit_symmetry:
+                # mirror the legacy single-dot branch: beyond the startup
+                # phase only <v_{i+1-2l}, z> is new, the rest comes from
+                # the symmetric fill of (K2)
+                vd = jnp.where(ph_pay < 2 * l - 1, vd_full,
+                               jnp.zeros_like(vd_full).at[0].set(vd_full[0]))
+            else:
+                vd = vd_full
+            vmask = jnp.arange(l + 1) + (ph_pay + 1 - 2 * l) >= 0
+            payload = jnp.concatenate([vd[::-1] * vmask, zd[::-1]])
+            if stab:
+                payload = jnp.concatenate([payload, slotW[None]])
         return finalize(st, ph, payload, q_aux, brk, x2, p2, eta2, zeta2, k2,
                         Vw2, Zw2, Zhw2, Gb2, gam2, dlt2, **seed_kw)
 
@@ -870,7 +917,8 @@ def plcg_scan(
     return PLCGOut(x=final.x, resnorms=resnorms, k_done=final.k_done,
                    converged=final.converged, breakdown=final.breakdown,
                    committed=committed, restarts=final.restarts,
-                   replacements=final.repl)
+                   replacements=final.repl,
+                   trips=jnp.asarray(iters, jnp.int32))
 
 
 def plcg_jit(matvec, b, x0=None, *, l, iters, sigma, tol=0.0, prec=None,
@@ -960,9 +1008,71 @@ def _jitted_sweep(matvec, l, iters, sigma, tol, prec, exploit_symmetry,
         build)
 
 
+def count_bodies(trips, l: int, k_done, committed=None,
+                 lanes: Optional[int] = None) -> None:
+    """Add one sweep's scan bodies to the open root span's counters
+    (``repro.core.telemetry``).
+
+    ``bodies`` sums ``trips``, the fetched trip count the sweep returns
+    (``PLCGOut.trips``: a scalar, or one per lane of a batch), over every
+    lane.  ``useful`` sums, over the first ``lanes`` lanes (all by
+    default; the rest are padding), the body index of each lane's last
+    committed update plus one: from the fetched ``committed`` mask where
+    the caller has it, else ``l + k_done + 1`` (update k commits at body
+    l + k)."""
+    if committed is not None:
+        m = np.asarray(committed, dtype=bool)
+        m = m.reshape(-1, m.shape[-1])
+        last = np.where(m.any(axis=1),
+                        m.shape[-1] - np.argmax(m[:, ::-1], axis=1), 0)
+    else:
+        last = l + np.asarray(k_done).reshape(-1) + 1
+    telemetry.count("bodies", int(np.sum(trips)))
+    telemetry.count("useful", int(last[:lanes].sum()))
+
+
+def read_batched(out, *, l: int, stab: bool, lanes: Optional[int] = None):
+    """The host side of one batched sweep: ``out`` holds its device
+    ``(resnorms, converged, breakdown, k_done, committed, restarts,
+    replacements, trips)``, each lane's row first.  Reads them in that
+    order, one ``plcg.fetch`` each (``committed`` / ``restarts`` /
+    ``replacements`` only on the in-scan ``stab`` path; ``trips`` rides
+    the ``k_done`` read), counts the sweep's bodies
+    (:func:`count_bodies`; lanes past the first ``lanes`` are padding) and
+    builds each lane's residual history in a ``plcg.unpack`` span.
+    Returns ``(resnorms lists, converged, breakdown, k_done, restarts,
+    replacements)`` on the host."""
+    resn, conv, brk, k_done, committed, restarts, repl, trips = out
+    resn = telemetry.fetch(resn, "resnorms")            # (nrhs, iters)
+    conv = telemetry.fetch(conv, "converged")
+    brk = telemetry.fetch(brk, "breakdown")
+    k_done, trips = telemetry.fetch((k_done, trips), "k_done")
+    if stab:
+        # restart / replacement dead bodies interleave with committed
+        # updates, so the in-order residual history is the committed mask
+        # (not a contiguous count slice)
+        committed = telemetry.fetch(committed, "committed", dtype=bool)
+        restarts = telemetry.fetch(restarts, "restarts")
+        repl = telemetry.fetch(repl, "replacements")
+        count_bodies(trips, l, k_done, committed=committed, lanes=lanes)
+        with telemetry.span("plcg.unpack"):
+            resnorms = [[float(r) for r in row[m]]
+                        for row, m in zip(resn, committed)]
+        return resnorms, conv, brk, k_done, restarts, repl
+    count_bodies(trips, l, k_done, lanes=lanes)
+    # lane j commits |zeta_k| for k = 0..k_done[j] at trace indices
+    # l..l+k_done[j]; slicing by count (not value-filtering) keeps a
+    # legitimate exact-zero residual in the trace
+    with telemetry.span("plcg.unpack"):
+        resnorms = [[float(r) for r in row[l: l + int(k) + 1]]
+                    for row, k in zip(resn, k_done)]
+    zeros = np.zeros(conv.shape[0], dtype=int)
+    return resnorms, conv, brk, k_done, zeros, zeros
+
+
 def run_restart_driver(sweep, b, x0, *, tol: float, maxiter: int,
-                       max_restarts: int, bnorm: float,
-                       in_scan: bool = False):
+                       max_restarts: int, bnorm: float, l: int,
+                       in_scan: bool = False, program=None):
     """Restart-on-breakdown with a global iteration budget (paper
     Remark 8), shared by the single-device and mesh drivers -- the ONE
     place restart semantics (budget accounting, happy breakdown,
@@ -972,9 +1082,9 @@ def run_restart_driver(sweep, b, x0, *, tol: float, maxiter: int,
     ends) runs ONE sweep that was built with ``restart=``/``rr_period=``
     -- breakdown recovery happens per lane inside the compiled scan
     (Ritz-refreshed shifts, zero host round-trips) and this wrapper only
-    unpacks the result.  ``sweep(b, x, budget)`` must then return
-    ``(x, resnorms, converged, breakdown, k_done, committed, restarts,
-    replacements)``.
+    unpacks the result.  ``sweep(b, x, budget)`` returns ``(x, resnorms,
+    converged, breakdown, k_done, committed, restarts, replacements,
+    trips)`` on either path, ``trips`` being the bodies it ran.
 
     ``in_scan=False`` is the legacy host loop retained for parity
     testing and as a compatibility escape hatch: the sweep is re-entered
@@ -982,30 +1092,45 @@ def run_restart_driver(sweep, b, x0, *, tol: float, maxiter: int,
     .. deprecated:: its shift-free re-init (the restarted sweep reuses
        the original sigma instead of Ritz-refreshing) and its
        single-RHS-only reach are superseded by the in-scan path.
-    ``sweep`` returns at least ``(x, resnorms, converged, breakdown,
-    k_done)``; extra trailing outputs are ignored.
+    The loop reads ``x``, ``resnorms``, ``converged``, ``breakdown``,
+    ``k_done`` and ``trips`` of each pass.
 
     Either way a breakdown-looping system performs at most ``maxiter``
     updates in total (not ``max_restarts x maxiter``); happy breakdown
     at tolerance counts as convergence.  Returns
     ``(x, resnorms list, info dict)``.
+
+    Every pass is a ``plcg.dispatch`` of ``sweep`` (``program``, the
+    jitted callable it runs, tells whether the call compiled; ``sweep``
+    itself by default), a ``plcg.wait`` and one ``plcg.fetch`` per read
+    of an output (``trips`` rides the ``k_done`` read), and counts its
+    bodies (:func:`count_bodies`, depth ``l``) on the open root span.
     """
     if in_scan:
+        out = telemetry.dispatch(sweep, b, x0, maxiter, program=program)
+        telemetry.wait(out)
         (x, resn, conv, brk, k_done, committed, n_restarts,
-         n_repl) = sweep(b, x0, maxiter)
-        mask = np.asarray(committed, dtype=bool)
-        resnorms = [float(r) for r in np.asarray(resn)[mask]]
-        converged = bool(conv)
-        breakdown = bool(brk)
+         n_repl, trips) = out
+        mask = telemetry.fetch(committed, "committed", dtype=bool)
+        resn_h = telemetry.fetch(resn, "resnorms")
+        converged = bool(telemetry.fetch(conv, "converged"))
+        breakdown = bool(telemetry.fetch(brk, "breakdown"))
+        n_restarts = int(telemetry.fetch(n_restarts, "restarts"))
+        n_repl = int(telemetry.fetch(n_repl, "replacements"))
+        k_done, trips = telemetry.fetch((k_done, trips), "k_done")
+        k_done = int(k_done)
+        count_bodies(trips, l, k_done, committed=mask)
+        with telemetry.span("plcg.unpack"):
+            resnorms = [float(r) for r in resn_h[mask]]
         if (not converged and breakdown and resnorms
                 and resnorms[-1] <= 4 * tol * bnorm):
             converged = True              # happy breakdown at tolerance
         return x, resnorms, {
             "converged": converged,
-            "breakdowns": int(n_restarts) + int(breakdown),
-            "restarts": int(n_restarts),
-            "replacements": int(n_repl),
-            "iterations": int(k_done) + 1,
+            "breakdowns": n_restarts + int(breakdown),
+            "restarts": n_restarts,
+            "replacements": n_repl,
+            "iterations": k_done + 1,
         }
     x = x0
     # every (re-)entry must present the SAME placement to hit one
@@ -1026,15 +1151,22 @@ def run_restart_driver(sweep, b, x0, *, tol: float, maxiter: int,
     while total_k < maxiter:
         remaining = maxiter - total_k
         if x0_sharding is not None:
-            import jax
-            x = jax.device_put(x, x0_sharding)
-        x, resn, conv, brk, k_done = sweep(b, x, remaining)[:5]
-        resnorms.extend(float(r) for r in np.asarray(resn) if r > 0)
-        total_k += max(int(k_done) + 1, 1)
-        if bool(conv):
+            with telemetry.span("plcg.prepare"):
+                x = jax.device_put(x, x0_sharding)
+        out = telemetry.dispatch(sweep, b, x, remaining, program=program)
+        telemetry.wait(out)
+        x, resn, conv, brk, k_done = out[:5]
+        resn_h = telemetry.fetch(resn, "resnorms")
+        with telemetry.span("plcg.unpack"):
+            resnorms.extend(float(r) for r in resn_h if r > 0)
+        k, trips = telemetry.fetch((k_done, out[8]), "k_done")
+        k = int(k)
+        count_bodies(trips, l, k)
+        total_k += max(k + 1, 1)
+        if bool(telemetry.fetch(conv, "converged")):
             converged = True
             break
-        if bool(brk):
+        if bool(telemetry.fetch(brk, "breakdown")):
             breakdowns += 1
             if resnorms and resnorms[-1] <= 4 * tol * bnorm:
                 converged = True          # happy breakdown at tolerance
@@ -1077,34 +1209,39 @@ def plcg_solve(matvec, b, x0=None, *, l, sigma, tol=1e-8, maxiter=1000,
     (``maxiter + l + 1`` plus ``stab_iter_slack`` on the in-scan path).
 
     ``context`` (optional) switches to the bindable-operator protocol:
-    ``matvec`` is then a two-argument ``matvec_ctx(context, v)`` and the
-    context pytree is threaded through the jitted sweep as a traced
-    operand (no retrace when it is rebound between solves).
+    ``matvec`` is then a two-argument ``matvec_ctx(context, v)``, the
+    sweep (given or built) takes ``(context, b, x0, k_budget)``, and the
+    context pytree is threaded through it as a traced operand (no
+    retrace when it is rebound between solves).
 
     Returns (x, resnorms, info dict).
     """
-    x0 = jnp.zeros_like(b) if x0 is None else x0
-    bnorm = float(jnp.linalg.norm(b))
+    with telemetry.span("plcg.prepare"):
+        x0 = jnp.zeros_like(b) if x0 is None else x0
+        norm = jnp.linalg.norm(b)
+        in_scan = restart is not None or residual_replacement is not None
+        iters = maxiter + l + 1 + stab_iter_slack(
+            l, restart, residual_replacement, maxiter)
+        program = sweep if sweep is not None else _jitted_sweep(
+            matvec, l, iters, tuple(sigma), tol, prec,
+            exploit_symmetry, unroll, backend, stencil_hw,
+            restart=restart, rr_period=residual_replacement,
+            ritz_refresh=ritz_refresh, precision=precision,
+            bindable=context is not None)
+        if context is None:
+            fn = program
+        else:
+            fn = lambda bb, xx, kb: program(context, bb, xx, kb)  # noqa: E731
+    bnorm = float(telemetry.fetch(norm, "bnorm"))
     if bnorm == 0:
         bnorm = 1.0
-    in_scan = restart is not None or residual_replacement is not None
-    iters = maxiter + l + 1 + stab_iter_slack(
-        l, restart, residual_replacement, maxiter)
-    fn = sweep if sweep is not None else _jitted_sweep(
-        matvec, l, iters, tuple(sigma), tol, prec,
-        exploit_symmetry, unroll, backend, stencil_hw,
-        restart=restart, rr_period=residual_replacement,
-        ritz_refresh=ritz_refresh, precision=precision,
-        bindable=context is not None)
-    if context is not None and sweep is None:
-        raw = fn
-        fn = lambda bb, xx, kb: raw(context, bb, xx, kb)  # noqa: E731
 
     def run_sweep(bb, xx, remaining):
         out = fn(bb, xx, remaining)
         return (out.x, out.resnorms, out.converged, out.breakdown,
-                out.k_done, out.committed, out.restarts, out.replacements)
+                out.k_done, out.committed, out.restarts, out.replacements,
+                out.trips)
 
     return run_restart_driver(run_sweep, b, x0, tol=tol, maxiter=maxiter,
-                              max_restarts=max_restarts, bnorm=bnorm,
-                              in_scan=in_scan)
+                              max_restarts=max_restarts, bnorm=bnorm, l=l,
+                              in_scan=in_scan, program=program)

@@ -54,7 +54,7 @@ from typing import Any, Optional
 
 import numpy as np
 
-from . import engine
+from . import engine, telemetry
 from .linop import LinearOperator, is_bindable
 from .results import SolveResult
 
@@ -69,13 +69,16 @@ class SolveHandle:
     ``done`` is True once a flush has produced this request's result;
     ``result()`` drains the owning queue on demand (so a bare
     ``solver.submit(b).result()`` is a correct, if unbatched, call).
+    ``request`` is the request id its ``solver.submit`` span carries
+    (``repro.core.telemetry``), which the flush that solves it serves.
     """
 
-    __slots__ = ("_owner", "_result")
+    __slots__ = ("_owner", "_result", "request")
 
-    def __init__(self, owner):
+    def __init__(self, owner, request: int):
         self._owner = owner
         self._result: Optional[SolveResult] = None
+        self.request = request
 
     @property
     def done(self) -> bool:
@@ -93,12 +96,13 @@ class SolveHandle:
         self._result = result
 
 
-def _lane_result(rb: SolveResult, j: int, *, flush_nrhs: int,
-                 flush_pad: int) -> SolveResult:
-    """Extract lane ``j`` of a batched SolveResult as a single-RHS
-    SolveResult (the per-handle contract of pooled dispatch)."""
+def _lane_result(rb: SolveResult, xs: np.ndarray, j: int, *,
+                 flush_nrhs: int, flush_pad: int) -> SolveResult:
+    """Extract lane ``j`` of a batched SolveResult, whose ``x`` the caller
+    fetched once as ``xs``, as a single-RHS SolveResult (the per-handle
+    contract of pooled dispatch)."""
     info = rb.info
-    x = np.asarray(rb.x)[j]
+    x = xs[j]
     conv = info.get("per_rhs_converged")
     iters = info.get("per_rhs_iters")
     brk = info.get("per_rhs_breakdown")
@@ -194,8 +198,7 @@ class Solver:
         self.options = dict(options)
         self._pending: list = []
         self._prepared: dict = {}       # strong refs: config -> jitted fn
-        self.stats = {"calls": 0, "prepared_builds": 0, "flushes": 0,
-                      "flushed_rhs": 0, "padded_lanes": 0}
+        self.stats = {"calls": 0, "prepared_builds": 0}
 
         self._mesh_session = None
         if on_mesh:
@@ -330,7 +333,18 @@ class Solver:
         """Solve ``A x = b`` with the prepared session (same result
         contract as :func:`repro.core.solve`, including stacked batches).
         ``tol``/``maxiter`` default to the session values; an override
-        prepares (and strongly holds) an additional sweep."""
+        prepares (and strongly holds) an additional sweep.  One
+        ``solver.solve`` root span (``repro.core.telemetry``)."""
+        with telemetry.span("solver.solve") as root:
+            r = self._solve(b, x0, tol=tol, maxiter=maxiter)
+            root.requests = telemetry.request_ids(r.info.get("nrhs", 1))
+        return r
+
+    __call__ = solve
+
+    def _solve(self, b, x0=None, *, tol: Optional[float] = None,
+               maxiter: Optional[int] = None) -> SolveResult:
+        """:meth:`solve` inside the caller's root span."""
         tol = self.tol if tol is None else tol
         maxiter = self.maxiter if maxiter is None else maxiter
         self.stats["calls"] += 1
@@ -350,13 +364,12 @@ class Solver:
                                 if spec.batched == "vmap" else None),
                     **self.options)
             elif spec.name == "plcg_scan":
-                sweep = self._single_sweep(tol, maxiter)
-                if is_bindable(op):
-                    # bind the CURRENT context at call time: the raw
-                    # prepared sweep (kept in _prepared for the
-                    # compile_counts gate) takes it as a traced operand
-                    raw, ctx = sweep, op.context
-                    sweep = lambda bb, xx, kb: raw(ctx, bb, xx, kb)  # noqa: E731
+                # a bindable operator's CURRENT context is bound at call
+                # time (plcg_solve's context=): the raw prepared sweep
+                # (kept in _prepared for the compile_counts gate) takes it
+                # as a traced operand
+                with telemetry.span("plcg.prepare"):
+                    sweep = self._single_sweep(tol, maxiter)
                 r = engine._run_plcg_scan(
                     op, b, x0, tol=tol, maxiter=maxiter, M=self.M, l=self.l,
                     sigma=self.sigma, spectrum=self.spectrum,
@@ -374,8 +387,6 @@ class Solver:
         if self.auto is not None:
             r.info["auto"] = self.auto.as_info()
         return r
-
-    __call__ = solve
 
     def lower(self, b, x0=None):
         """Lower, without running it, the program that ``solve(b, x0)``
@@ -416,9 +427,13 @@ class Solver:
 
         Nothing runs until a flush -- triggered explicitly
         (:meth:`flush` / ``SolverPool.flush``) or implicitly by
-        ``handle.result()``."""
-        handle = SolveHandle(_owner if _owner is not None else self)
-        self._pending.append((b, x0, handle))
+        ``handle.result()``.  One ``solver.submit`` root span, whose id is
+        the request id (``handle.request``)."""
+        with telemetry.span("solver.submit") as root:
+            root.requests = (root.id,)
+            handle = SolveHandle(_owner if _owner is not None else self,
+                                 root.id)
+            self._pending.append((b, x0, handle))
         return handle
 
     @property
@@ -432,8 +447,9 @@ class Solver:
         Chunks of at most ``max_batch`` (default: everything in one) are
         padded up to the smallest bucket >= the chunk size (default: no
         padding) by duplicating lane 0, solved through the batched
-        engine, and unpacked into the per-handle results.  Returns a
-        list of ``(real, padded)`` flush records.
+        engine, and unpacked into the per-handle results; each chunk is
+        one ``solver.flush`` root span serving its requests' ids.
+        Returns a list of ``(real, padded)`` flush records.
         """
         records = []
         while self._pending:
@@ -455,58 +471,66 @@ class Solver:
         return records
 
     def _flush_chunk(self, chunk: list, buckets: Optional[tuple]) -> tuple:
-        import jax.numpy as jnp
         k = len(chunk)
+        can_batch = (self.spec.batched == "vmap"
+                     or self._mesh_session is not None)
         pad = k
-        if buckets:
+        if can_batch and buckets:
             for size in sorted(buckets):
                 if size >= k:
                     pad = size
                     break
-        can_batch = (self.spec.batched == "vmap"
-                     or self._mesh_session is not None)
-        if not can_batch:
-            # loop methods: per-RHS dispatch (restart semantics of the
-            # plain solve apply -- there is no batched sweep to share)
-            for b, x0, handle in chunk:
-                handle._set(self.solve(b, x0))
-            self.stats["flushes"] += 1
-            self.stats["flushed_rhs"] += k
-            return (k, k)
-        # batchable methods ALWAYS take the batched sweep, even for a
-        # lone request: pooled lanes must have one contract (masked
-        # single sweep, no data-dependent restarts) regardless of how
-        # many requests happened to be co-queued
-        bs = [jnp.asarray(b) for b, _, _ in chunk]
-        shape = bs[0].shape
-        if any(b.shape != shape for b in bs):
-            raise ValueError(
-                f"cannot micro-batch mixed RHS shapes "
-                f"{sorted({tuple(b.shape) for b in bs})}; flush per shape")
-        bs += [bs[0]] * (pad - k)               # pad lanes: duplicate lane 0
-        B = jnp.stack(bs)
-        X0 = None
-        if any(x0 is not None for _, x0, _ in chunk):
-            X0 = jnp.stack([jnp.zeros_like(bs[0]) if x0 is None
-                            else jnp.asarray(x0)
-                            for _, x0, _ in chunk]
-                           + [jnp.zeros_like(bs[0])] * (pad - k))
-        rb = self._solve_batched_for_pool(B, X0)
-        for j, (_, _, handle) in enumerate(chunk):
-            handle._set(_lane_result(rb, j, flush_nrhs=k, flush_pad=pad))
-        self.stats["flushes"] += 1
-        self.stats["flushed_rhs"] += k
-        self.stats["padded_lanes"] += pad - k
+        with telemetry.span("solver.flush",
+                            requests=[h.request for _, _, h in chunk],
+                            rhs=k, lanes=pad):
+            if can_batch:
+                self._solve_chunk(chunk, pad)
+            else:
+                # loop methods: per-RHS dispatch (restart semantics of the
+                # plain solve apply -- there is no batched sweep to share)
+                for b, x0, handle in chunk:
+                    handle._set(self._solve(b, x0))
         return (k, pad)
 
-    def _solve_batched_for_pool(self, B, X0) -> SolveResult:
+    def _solve_chunk(self, chunk: list, pad: int) -> None:
+        """One batched sweep over ``chunk`` padded to ``pad`` lanes, and
+        the per-handle results.  Batchable methods ALWAYS take the
+        batched sweep, even for a lone request: pooled lanes must have
+        one contract (masked single sweep, no data-dependent restarts)
+        regardless of how many requests happened to be co-queued."""
+        import jax.numpy as jnp
+        k = len(chunk)
+        with telemetry.span("plcg.prepare"):
+            bs = [jnp.asarray(b) for b, _, _ in chunk]
+            shape = bs[0].shape
+            if any(b.shape != shape for b in bs):
+                raise ValueError(
+                    f"cannot micro-batch mixed RHS shapes "
+                    f"{sorted({tuple(b.shape) for b in bs})}; "
+                    "flush per shape")
+            bs += [bs[0]] * (pad - k)           # pad lanes: duplicate lane 0
+            B = jnp.stack(bs)
+            X0 = None
+            if any(x0 is not None for _, x0, _ in chunk):
+                X0 = jnp.stack([jnp.zeros_like(bs[0]) if x0 is None
+                                else jnp.asarray(x0)
+                                for _, x0, _ in chunk]
+                               + [jnp.zeros_like(bs[0])] * (pad - k))
+        rb = self._solve_batched_for_pool(B, X0, lanes=k)
+        xs = telemetry.fetch(rb.x, "x")         # every lane's x, one read
+        with telemetry.span("plcg.unpack"):
+            for j, (_, _, handle) in enumerate(chunk):
+                handle._set(_lane_result(rb, xs, j, flush_nrhs=k,
+                                         flush_pad=pad))
+
+    def _solve_batched_for_pool(self, B, X0, *, lanes: int) -> SolveResult:
         """Batched solve for pooled dispatch: legacy host-driver knobs
         (``max_restarts``, ``record_G``-style introspection) are stripped
         -- the batched engines would reject them loudly -- but the
         normalized in-scan stability knobs (``restart=`` /
         ``residual_replacement=``) thread through, so each pooled lane
         re-seeds itself independently inside the one masked sweep per
-        flush."""
+        flush.  Lanes past the first ``lanes`` are padding."""
         self.stats["calls"] += 1
         if self._mesh_session is not None:
             opts = {key: v for key, v in self.options.items()
@@ -525,7 +549,7 @@ class Solver:
                               residual_replacement=sess.residual_replacement,
                               precision=sess.precision,
                               get_sweep=sess._get_sweep("plcg", self.tol),
-                              **opts)
+                              lanes=lanes, **opts)
         op = self._ensure_op(B[0])
         opts = {key: v for key, v in self.options.items()
                 if key in ("exploit_symmetry", "unroll", "ritz_refresh")}
@@ -537,7 +561,7 @@ class Solver:
             precision=self.precision,
             get_engine=(self._batched_engine_getter()
                         if self.spec.batched == "vmap" else None),
-            **opts)
+            lanes=lanes, **opts)
 
 
 class SolverPool:
@@ -567,11 +591,9 @@ class SolverPool:
                 f"largest pad bucket {self.buckets[-1]} is below "
                 f"max_batch={self.max_batch}; a full chunk could not be "
                 "padded to any bucket")
-        self.stats = {"requests": 0, "flushes": 0, "batches": 0,
-                      "lanes_real": 0, "lanes_padded": 0}
+        self.stats = {"batches": 0, "lanes_real": 0, "lanes_padded": 0}
 
     def submit(self, b, x0=None) -> SolveHandle:
-        self.stats["requests"] += 1
         return self.solver.submit(b, x0, _owner=self)
 
     @property
@@ -583,7 +605,6 @@ class SolverPool:
         padded to the bucket ladder.  Returns the flush records."""
         records = self.solver.flush(max_batch=self.max_batch,
                                     buckets=self.buckets)
-        self.stats["flushes"] += 1
         self.stats["batches"] += len(records)
         for real, padded in records:
             self.stats["lanes_real"] += real

@@ -58,10 +58,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map_compat
 from repro.core import engine as _engine
+from repro.core import telemetry
 from repro.core.comm import as_comm_policy, build_comm_runtime
+from repro.core.plcg_scan import (plcg_scan, read_batched,
+                                  run_restart_driver, stab_iter_slack)
 from repro.core.precision import as_precision_policy
-from repro.core.plcg_scan import (plcg_scan, run_restart_driver,
-                                  stab_iter_slack)
 from repro.core.results import SolveResult
 from repro.core.solver_cache import WeakCallableCache
 
@@ -167,8 +168,8 @@ def plcg_mesh_sweep(op: DistributedOperator, *, l: int, iters: int,
     """Build (cached) the jitted p(l)-CG mesh sweep.
 
     Returns a jitted callable ``(b, x0, k_budget) -> (x, resnorms,
-    converged, breakdown, k_done, committed, restarts, replacements)``
-    where ``b``/``x0`` are global fields
+    converged, breakdown, k_done, committed, restarts, replacements,
+    trips)`` where ``b``/``x0`` are global fields
     of shape ``op.global_shape`` (``(nrhs, *global_shape)`` when
     ``batched``) and ``k_budget`` is the (traced) solution-update budget
     -- the restart driver passes the *remaining* global budget per sweep
@@ -233,7 +234,7 @@ def plcg_mesh_sweep(op: DistributedOperator, *, l: int, iters: int,
             )
             return (out.x.reshape(b_blk.shape), out.resnorms, out.converged,
                     out.breakdown, out.k_done, out.committed, out.restarts,
-                    out.replacements)
+                    out.replacements, out.trips)
 
         if bind:
             # the context is a traced leading operand of the shard_map
@@ -248,7 +249,7 @@ def plcg_mesh_sweep(op: DistributedOperator, *, l: int, iters: int,
                 return scan_body(opref.matvec_local, b_blk, x_blk, k_budget)
             ctx_specs = None
 
-        return _shard_jit(op, one, batched=batched, n_extra=1, n_out=7,
+        return _shard_jit(op, one, batched=batched, n_extra=1, n_out=8,
                           trace_event=lambda shape: ("plcg@mesh", shape, l),
                           ctx_specs=ctx_specs)
 
@@ -388,8 +389,14 @@ def _mesh_plcg(op, b, x0, *, tol, maxiter, l, sigma, prec=None,
                exploit_symmetry: bool = True,
                max_restarts=None, comm=None, restart=None,
                residual_replacement=None, ritz_refresh: bool = True,
-               precision=None, get_sweep=None) -> SolveResult:
-    b, x0, batched, orig_shape = _canonicalize_b(op, b, x0)
+               precision=None, get_sweep=None, lanes=None) -> SolveResult:
+    """p(l)-CG on the mesh: one RHS through the shared restart driver, or
+    a stacked batch through one sweep whose lanes past the first
+    ``lanes`` (default: none) are padding, counted in the ``bodies``
+    telemetry counter and not in ``useful``."""
+    with telemetry.span("plcg.prepare"):
+        b, x0, batched, orig_shape = _canonicalize_b(op, b, x0)
+        norm = None if batched else jnp.linalg.norm(b)
     sig = tuple(sigma)
     policy = as_comm_policy(comm)
     pp = as_precision_policy(precision)
@@ -408,14 +415,18 @@ def _mesh_plcg(op, b, x0, *, tol, maxiter, l, sigma, prec=None,
                                    restart=restart,
                                    rr_period=residual_replacement,
                                    ritz_refresh=ritz_refresh, precision=pp)
-    if _is_bindable_dist(op):
-        # bind the CURRENT context at call time; the raw sweep (cached /
-        # strongly held by a session) takes it as a traced operand
-        raw_get = get_sweep
+    bind = _is_bindable_dist(op)
 
-        def get_sweep(*, iters, batched):
-            raw, ctx = raw_get(iters=iters, batched=batched), op.context
-            return lambda bb, xx, kb: raw(ctx, bb, xx, kb)
+    def sweep_of(iters, batched):
+        """``(callable, jitted program)``: a bindable operator's CURRENT
+        context is bound at call time; the raw sweep (cached / strongly
+        held by a session) takes it as a traced operand."""
+        with telemetry.span("plcg.prepare"):
+            raw = get_sweep(iters=iters, batched=batched)
+        if not bind:
+            return raw, raw
+        ctx = op.context
+        return (lambda bb, xx, kb: raw(ctx, bb, xx, kb)), raw
     base_info = {"l": l, "sigma": list(sig), "backend": None,
                  "mesh": dict(op.mesh.shape), "comm": policy.mode,
                  "precision": None if pp.is_default else pp,
@@ -441,28 +452,13 @@ def _mesh_plcg(op, b, x0, *, tol, maxiter, l, sigma, prec=None,
         # one sweep, per-lane convergence masking inside the scan; with
         # restart=/residual_replacement= lanes also re-seed themselves
         # in-trace (still ONE compiled sweep, zero host round-trips)
-        fn = get_sweep(iters=maxiter + l + 1 + slack, batched=True)
-        out = fn(b, x0, maxiter + 1)
-        x, resn, conv, brk, k_done, committed, restarts, repl = out
-        resn = np.asarray(resn)                         # (nrhs, iters)
-        conv = np.asarray(conv)
-        brk = np.asarray(brk)
-        k_done = np.asarray(k_done)
-        if stab:
-            committed = np.asarray(committed, dtype=bool)
-            resnorms = [[float(r) for r in row[m]]
-                        for row, m in zip(resn, committed)]
-            restarts_pl = np.asarray(restarts)
-            repl_pl = np.asarray(repl)
-        else:
-            # lane j commits |zeta_k| for k = 0..k_done[j] at trace
-            # indices l..l+k_done[j] (count-sliced, as the vmap engine)
-            resnorms = [[float(r) for r in row[l: l + int(k) + 1]]
-                        for row, k in zip(resn, k_done)]
-            restarts_pl = np.zeros(int(b.shape[0]), dtype=int)
-            repl_pl = np.zeros(int(b.shape[0]), dtype=int)
+        fn, program = sweep_of(maxiter + l + 1 + slack, True)
+        out = telemetry.dispatch(fn, b, x0, maxiter + 1, program=program)
+        telemetry.wait(out)
+        (resnorms, conv, brk, k_done, restarts_pl, repl_pl) = read_batched(
+            out[1:], l=l, stab=stab, lanes=lanes)
         return SolveResult(
-            x=x.reshape(orig_shape),
+            x=out[0].reshape(orig_shape),
             resnorms=resnorms,
             iters=int(k_done.max()) + 1,
             converged=bool(conv.all()),
@@ -487,13 +483,14 @@ def _mesh_plcg(op, b, x0, *, tol, maxiter, l, sigma, prec=None,
     # a traced operand of ONE fixed-size compiled program, so restarts
     # never retrace/recompile the shard_map sweep.
     if stab:
-        fn = get_sweep(iters=maxiter + l + 1 + slack, batched=False)
+        fn, program = sweep_of(maxiter + l + 1 + slack, False)
     else:
-        fn = get_sweep(iters=maxiter + l, batched=False)
+        fn, program = sweep_of(maxiter + l, False)
     x, resnorms, info = run_restart_driver(
         fn, b, x0, tol=tol, maxiter=maxiter,
         max_restarts=5 if max_restarts is None else max_restarts,
-        bnorm=float(jnp.linalg.norm(b)) or 1.0, in_scan=stab)
+        bnorm=float(telemetry.fetch(norm, "bnorm")) or 1.0, l=l,
+        in_scan=stab, program=program)
     return SolveResult(
         x=x.reshape(orig_shape), resnorms=resnorms,
         iters=info["iterations"], converged=info["converged"],
