@@ -49,9 +49,9 @@ POOL_REQUESTS = 16
 POOL_BATCH = 8
 L = 3
 TOL = 1e-5
-#: the scan engine runs all maxiter + l + 1 bodies, frozen after
-#: convergence, so the solve time follows maxiter: 300 is 2.3x the 132
-#: iterations this problem takes on the chip (the config's cap is 2000)
+#: the engine stops once every lane is done, so maxiter only caps the
+#: loop: 300 is 2.3x the 132 iterations this problem takes on the chip
+#: (the config's cap is 2000)
 MAXITER = 300
 SPECTRUM = (0.0, 8.0)
 

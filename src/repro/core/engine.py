@@ -8,21 +8,22 @@ registry that every solver registers into with a common
   ``cg``         classic Hestenes-Stiefel CG (paper Alg. 4)
   ``pcg``        Ghysels-Vanroose pipelined CG, depth 1 (paper Alg. 5)
   ``plcg``       deep-pipelined p(l)-CG, python reference (paper Alg. 2)
-  ``plcg_scan``  jitted ``lax.scan`` p(l)-CG production engine (Alg. 3)
+  ``plcg_scan``  jitted p(l)-CG production engine (Alg. 3), early exit
   ``dlanczos``   direct Lanczos (exact-arithmetic oracle, Remark 7)
   ``plminres``   deep-pipelined MINRES (paper Remark 6; indefinite OK)
   =============  ========================================================
 
 Batched multi-RHS: a 2-D right-hand side ``B`` of shape ``(nrhs, n)``
 solves all systems at once.  For the scan-engine methods (``plcg``,
-``plcg_scan``) the batch runs as **one jitted ``vmap`` of the
-``lax.scan`` engine** -- a single XLA compilation, a single fused program
+``plcg_scan``) the batch runs as **one jitted loop over the ``vmap`` of
+the engine body** -- a single XLA compilation, a single fused program
 in which every per-iteration reduction covers all right-hand sides.
-Per-RHS convergence is masked inside the scan: a converged column's
+Per-RHS convergence is masked inside the loop: a converged column's
 state is frozen through the ``jnp.where``/``lax.select`` commit gate of
 the engine body (under ``vmap`` that gate batches into a per-lane
 ``select``), mirroring how the paper's pipeline keeps all lanes busy
-while individual systems finish at different iterations.  Methods
+while individual systems finish at different iterations; the loop
+itself stops after the body in which the last lane is done.  Methods
 without a batched engine fall back to a loop of single-RHS solves.
 
 The ``backend`` switch ("fused" | "pallas" | "ref" | "auto" | None)
@@ -63,7 +64,7 @@ Array = Any
 
 _REGISTRY: dict[str, "MethodSpec"] = {}
 
-#: Trace-time log of the batched vmap(scan) engine: one entry is appended
+#: Trace-time log of the batched engine: one entry is appended
 #: each time XLA *traces* (= compiles) the batched engine (single-device
 #: and mesh-aware), so tests can assert that a batched ``solve(A, B)``
 #: compiles exactly once.
@@ -88,7 +89,7 @@ class MethodSpec:
     ``fn(A, b, x0, *, tol, maxiter, M, l, sigma, spectrum, backend, **opts)``
     must return a :class:`SolveResult`.  ``batched`` is ``"vmap"`` when the
     method is backed by the jittable scan engine (batch solves run as one
-    ``jit(vmap(scan))``) and ``"loop"`` otherwise.  ``supports_M`` /
+    jitted loop over the ``vmap`` of its body) and ``"loop"`` otherwise.  ``supports_M`` /
     ``supports_mesh`` are the capability flags :func:`solve` checks up
     front -- the single source of truth replacing per-adapter
     ``ValueError``s, so every method rejects an unsupported ``M=`` /
@@ -722,7 +723,7 @@ def _solve_batched(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
     )
 
 
-#: Jitted vmap(scan) engines, keyed weakly on the operator/preconditioner
+#: Jitted batched engines, keyed weakly on the operator/preconditioner
 #: callables (see solver_cache; cleared by ``clear_solver_cache``).
 _BATCH_CACHE = solver_cache.WeakCallableCache(maxsize=16)
 
@@ -732,7 +733,7 @@ def _batched_engine(method_name: str, matvec, l: int, iters: int, sigma,
                     backend, stencil_hw, restart=None, rr_period=None,
                     ritz_refresh: bool = True, k_budget=None,
                     precision=None, bindable: bool = False):
-    """Jitted vmap(scan) engine, cached per configuration so repeated
+    """Jitted batched engine, cached per configuration so repeated
     batched solves with the same operator/settings compile only once.
 
     Keyed on ``matvec``/``prec`` object identity through weak references:
@@ -743,7 +744,7 @@ def _batched_engine(method_name: str, matvec, l: int, iters: int, sigma,
 
     ``bindable=True`` interprets ``matvec`` as ``matvec_ctx(context, v)``
     and the returned engine takes ``(context, B, X0)``: the context is a
-    traced operand shared by every lane (``in_axes=(None, 0, 0)``), so
+    traced operand shared by every lane, so
     rebinding operator data between batched solves reuses the compiled
     program."""
 
@@ -761,17 +762,15 @@ def _batched_engine(method_name: str, matvec, l: int, iters: int, sigma,
             ritz_refresh=ritz_refresh, k_budget=k_budget,
             precision=precision)
 
+        # the engine takes the stacked (nrhs, n) lanes itself: it vmaps
+        # its body inside one loop that stops when every lane is done
         if bindable:
-            def engine_ctx(ctx, bb, xx):
-                return _plcg_scan_engine(lambda v: mv(ctx, v), bb, xx,
-                                         **kwargs)
-
             def _batched_ctx(ctx, Bb, Xb):
                 if len(BATCH_TRACE_EVENTS) < 4096:
                     BATCH_TRACE_EVENTS.append(
                         (method_name, tuple(Bb.shape), l))
-                return jax.vmap(engine_ctx,
-                                in_axes=(None, 0, 0))(ctx, Bb, Xb)
+                return _plcg_scan_engine(lambda v: mv(ctx, v), Bb, Xb,
+                                         **kwargs)
 
             return jax.jit(_batched_ctx)
 
@@ -782,7 +781,7 @@ def _batched_engine(method_name: str, matvec, l: int, iters: int, sigma,
             # the test suite can assert the batch compiles exactly once
             if len(BATCH_TRACE_EVENTS) < 4096:  # bounded in long processes
                 BATCH_TRACE_EVENTS.append((method_name, tuple(Bb.shape), l))
-            return jax.vmap(engine)(Bb, Xb)
+            return engine(Bb, Xb)
 
         return jax.jit(_batched)
 
@@ -813,7 +812,7 @@ def _batched_program(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
         # (trace_gaps, record_G, max_restarts, ...)
         raise ValueError(
             f"options {sorted(options)} are not supported by the batched "
-            "vmap(scan) engine; solve each RHS individually (1-D b) or "
+            "engine; solve each RHS individually (1-D b) or "
             "use a loop-batched method (cg, pcg, dlanczos, plminres)")
     sig = tuple(_resolve_sigma(sigma, spectrum, l))
     Bj = jnp.asarray(B)
@@ -860,11 +859,11 @@ def _solve_batched_vmap(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
                         ritz_refresh: bool = True,
                         get_engine=None, lanes=None,
                         **options) -> SolveResult:
-    """One jitted ``vmap`` of the scan engine over the stacked RHS.
+    """One jitted batched engine over the stacked RHS.
 
     A single XLA compilation covers all ``nrhs`` systems; converged lanes
     freeze via the engine's per-lane commit select while the remaining
-    lanes keep iterating.  Runs ONE sweep always: with ``restart=`` /
+    lanes keep iterating, and the loop stops once every lane is done.  Runs ONE sweep always: with ``restart=`` /
     ``rr_period=`` (normalized by ``_prepare_restart``) each lane
     re-seeds itself in-trace on breakdown / on the replacement period --
     recovery is per lane, inside the same compiled program, never a
@@ -999,7 +998,7 @@ def _run_plcg_scan(A, b, x0, *, tol, maxiter, M, l, sigma, spectrum,
           options=("exploit_symmetry", "max_restarts", "unroll",
                    "ritz_refresh"),
           mesh_options=("exploit_symmetry", "max_restarts", "ritz_refresh"),
-          description="jitted lax.scan p(l)-CG production engine (Alg. 3)")
+          description="jitted p(l)-CG production engine (Alg. 3)")
 def _method_plcg_scan(A, b, x0=None, *, tol=1e-8, maxiter=1000, M=None, l=1,
                       sigma=None, spectrum=None, backend=None, **kw):
     return _run_plcg_scan(A, b, x0, tol=tol, maxiter=maxiter, M=M, l=l,

@@ -14,11 +14,17 @@ This is the TPU-native realization of paper Alg. 2 + Alg. 3:
   2l+1-entry band of G's column c;
 * the 2l+1 dot products of iteration i form one fused payload (the paper's
   single ``MPI_Iallreduce``) that is pushed into a depth-l **in-flight
-  queue** carried through ``lax.scan`` state and *read l iterations later*
+  queue** carried through the loop state and *read l iterations later*
   (the ``MPI_Wait`` of Alg. 3).  Nothing in body i consumes the freshly
   reduced payload, so XLA's latency-hiding scheduler / collective pipeliner
   is free to overlap the all-reduce with the l interleaved SPMVs -- the
-  compiler-scheduled equivalent of asynchronous MPI progress.
+  compiler-scheduled equivalent of asynchronous MPI progress;
+* the bodies run in a device-side ``lax.while_loop`` that stops after the
+  body in which the last lane is done (converged, broken down or out of
+  budget), or after ``iters`` bodies: time follows the iterations a solve
+  needs, not its cap.  A stacked batch runs ``vmap`` of the body inside
+  the one loop, so lanes that finish early are frozen by the body's own
+  commit select until the last one is done.
 
 ``dot_local`` and ``reduce_payload`` are injected so the same engine drives:
   - the single-device path (dot = full dot, reduce = identity),
@@ -77,9 +83,18 @@ class PLCGState(NamedTuple):
     since_rr: jax.Array    # int32 committed updates since last (re)seed
 
 
+class _LaneConsts(NamedTuple):
+    """Per-lane constants the bodies read, carried beside the state so
+    that a batch can ``vmap`` the body (not the loop)."""
+    bnorm: jax.Array       # ||b||_M (1 where b = 0): the convergence scale
+    bC: Optional[jax.Array]   # b in the compute dtype (re-seeds only)
+    Mb: Optional[jax.Array]   # M b (re-seeds only)
+
+
 class PLCGOut(NamedTuple):
     x: jax.Array
-    resnorms: jax.Array    # (iters,) |zeta_k| per body (0 where not computed)
+    resnorms: jax.Array    # (iters,) |zeta_k| per body (0 where not computed
+    #                        or not run)
     k_done: jax.Array
     converged: jax.Array
     breakdown: jax.Array
@@ -88,8 +103,8 @@ class PLCGOut(NamedTuple):
     #                        order; robust to restarts scattering the rows)
     restarts: jax.Array    # in-scan restarts taken (0 on the legacy path)
     replacements: jax.Array  # residual replacements taken
-    trips: jax.Array       # int32 scan bodies this sweep ran (its trip
-    #                        count; the scan's static length)
+    trips: jax.Array       # int32 bodies this sweep ran: the loop's exit
+    #                        trip, <= iters (shared by a batch's lanes)
 
 
 def _default_dot(a, b):
@@ -133,18 +148,34 @@ def plcg_scan(
     ritz_refresh: bool = True,
     precision=None,
 ) -> PLCGOut:
-    """Run ``iters`` bodies of p(l)-CG (solution index reaches iters-l-1).
+    """Run p(l)-CG bodies until every lane is done, at most ``iters``
+    of them (solution index reaches at most iters-l-1).
 
-    All shapes are static; convergence/breakdown freeze the state.  Works
-    under jit / inside shard_map.  ``reduce_scalars(payload)`` performs the
-    global sum of a stacked scalar payload (identity on a single device,
-    ``psum`` in the distributed runtime) -- exactly one call per iteration.
+    ``b`` is one right-hand side ``(n,)`` or a stacked batch ``(nrhs,
+    n)`` (``x0`` alike): a batch runs the ``vmap`` of one body per loop
+    trip, each lane frozen by the body's commit select once it is done.
+    The loop stops after the body in which the last lane sets ``done``
+    (converged, broken down, or out of ``k_budget``) or after ``iters``
+    bodies; ``unroll`` bodies run back to back per loop trip.  A frozen
+    body changes no output, so the result equals running all ``iters``
+    bodies, and ``trips`` reports the bodies actually run.  Per-body
+    outputs (``resnorms``, ``committed``) are ``(iters,)`` buffers whose
+    unrun tail holds what a frozen body writes (0 and False).  Shapes
+    are static, so solves that stop at different bodies share one
+    compiled program.  Works under jit / inside shard_map, where every
+    device computes the same exit predicate: ``done`` derives from the
+    replicated reduction results under the blocking and ``"overlap"``
+    policies, and ``"ring"`` (whose devices accumulate in their own
+    order) agrees it with one scalar ``pmax`` over the ring's axes per
+    trip.  ``reduce_scalars(payload)`` performs the global sum of a
+    stacked scalar payload (identity on a single device, ``psum`` in the
+    distributed runtime) -- exactly one call per iteration.
 
-    ``k_budget`` (optional, may be a traced scalar) freezes the state --
-    without setting ``converged`` or ``breakdown`` -- once that many
-    solution updates have been committed: restart drivers with a global
-    iteration budget pass the *remaining* budget per sweep instead of
-    recompiling a differently-sized scan.
+    ``k_budget`` (optional, may be a traced scalar shared by all lanes)
+    freezes the state -- without setting ``converged`` or ``breakdown``
+    -- once that many solution updates have been committed: restart
+    drivers with a global iteration budget pass the *remaining* budget
+    per sweep instead of recompiling a differently-sized loop.
 
     ``comm`` (optional) is a resolved ``repro.core.comm.CommRuntime``
     selecting how the per-iteration reduction is realized inside the
@@ -301,7 +332,8 @@ def plcg_scan(
         # the element landing in slot j has completed l-1-j neighbor hops,
         # so the head (slot 0) is fully reduced iff l-1 >= len(schedule)
         # (validated at runtime construction) -- pure ppermute traffic,
-        # no all-reduce primitive at all
+        # no all-reduce primitive in the reduction (the loop's exit
+        # agreement below is one scalar pmax per trip)
         from .comm import ring_hop
         sched = comm.schedule
 
@@ -326,12 +358,11 @@ def plcg_scan(
         inflight0 = (jnp.zeros((l, P), cdt),
                      jnp.zeros((l, P), cdt))
 
+    lanes = b.ndim == 2
     x0 = jnp.zeros_like(b) if x0 is None else x0
-    x0 = x0.astype(cdt)
-    bC = b.astype(cdt)       # scalar-side view of b (init/reseed residuals)
     sig = jnp.asarray(list(sigma), dtype=cdt)
     ncols = iters + 2 * l + 2
-    n = b.shape[0]
+    n = b.shape[-1]
     # fused-tier dispatch on the preconditioner structure:
     #   fuse_diag    -- M^{-1} is a diagonal multiply (the inv_diag hint):
     #                   apply it in-kernel, staying at ONE launch/iteration;
@@ -362,38 +393,49 @@ def plcg_scan(
             raise ValueError(
                 f"prec_diag must be a scalar or ({n},), got {invd.shape}")
 
-    # ---- initialization (Alg. 2 lines 1-3) -------------------------------
-    rhat0 = bC - matvec(x0).astype(cdt)
-    r0 = prec(rhat0) if prec is not None else rhat0
-    Mb = prec(bC) if prec is not None else bC
-    init_pay = jnp.stack([dot(rhat0, r0), dot(bC, Mb)]).astype(cdt)
-    init_pay = red(init_pay)
-    beta0 = jnp.sqrt(init_pay[0])
-    bnorm = jnp.sqrt(init_pay[1])
-    bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
-    v0 = r0 / beta0
-
-    Zw = jnp.zeros((n, l + 1), sdt).at[:, 0].set(v0.astype(sdt))
-    Vw = jnp.zeros((n, W), sdt).at[:, 0].set(v0.astype(sdt))
-    Zhw = (jnp.zeros((n, 3), sdt).at[:, 0].set((rhat0 / beta0).astype(sdt))
-           if prec is not None else jnp.zeros((1, 1), sdt))
     Gb0 = jnp.zeros((ncols, W), cdt).at[0, 2 * l].set(1.0)
     use_ritz = stab and ritz_refresh
-    state = PLCGState(
-        Zw=Zw, Vw=Vw, Zhw=Zhw, Gb=Gb0,
-        gam=jnp.zeros(ncols, cdt), dlt=jnp.zeros(ncols, cdt),
-        inflight=inflight0,
-        x=x0, p=jnp.zeros_like(x0),
-        eta=jnp.asarray(0.0, cdt), zeta=jnp.asarray(0.0, cdt),
-        k_done=jnp.asarray(-1), done=jnp.asarray(False),
-        converged=jnp.asarray(False), breakdown=jnp.asarray(False),
-        ph=jnp.asarray(0, jnp.int32), wait=jnp.asarray(0, jnp.int32),
-        beta=beta0,
-        sig_c=(sig if use_ritz else jnp.zeros((), cdt)),
-        restarts=jnp.asarray(0, jnp.int32),
-        repl=jnp.asarray(0, jnp.int32),
-        since_rr=jnp.asarray(0, jnp.int32),
-    )
+
+    def init(b, x0):
+        """Alg. 2 lines 1-3 for one lane: its initial state and the
+        per-lane constants the bodies read."""
+        x0 = x0.astype(cdt)
+        bC = b.astype(cdt)   # scalar-side view of b (init/reseed residuals)
+        rhat0 = bC - matvec(x0).astype(cdt)
+        r0 = prec(rhat0) if prec is not None else rhat0
+        Mb = prec(bC) if prec is not None else bC
+        init_pay = jnp.stack([dot(rhat0, r0), dot(bC, Mb)]).astype(cdt)
+        init_pay = red(init_pay)
+        beta0 = jnp.sqrt(init_pay[0])
+        bnorm = jnp.sqrt(init_pay[1])
+        bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
+        v0 = r0 / beta0
+
+        Zw = jnp.zeros((n, l + 1), sdt).at[:, 0].set(v0.astype(sdt))
+        Vw = jnp.zeros((n, W), sdt).at[:, 0].set(v0.astype(sdt))
+        Zhw = (jnp.zeros((n, 3), sdt).at[:, 0].set(
+            (rhat0 / beta0).astype(sdt))
+            if prec is not None else jnp.zeros((1, 1), sdt))
+        state = PLCGState(
+            Zw=Zw, Vw=Vw, Zhw=Zhw, Gb=Gb0,
+            gam=jnp.zeros(ncols, cdt), dlt=jnp.zeros(ncols, cdt),
+            inflight=inflight0,
+            x=x0, p=jnp.zeros_like(x0),
+            eta=jnp.asarray(0.0, cdt), zeta=jnp.asarray(0.0, cdt),
+            k_done=jnp.asarray(-1), done=jnp.asarray(False),
+            converged=jnp.asarray(False), breakdown=jnp.asarray(False),
+            ph=jnp.asarray(0, jnp.int32), wait=jnp.asarray(0, jnp.int32),
+            # beta0 of the current phase; without the stability path it
+            # stays the initial one
+            beta=beta0,
+            sig_c=(sig if use_ritz else jnp.zeros((), cdt)),
+            restarts=jnp.asarray(0, jnp.int32),
+            repl=jnp.asarray(0, jnp.int32),
+            since_rr=jnp.asarray(0, jnp.int32),
+        )
+        return state, _LaneConsts(bnorm=bnorm,
+                                  bC=bC if stab else None,
+                                  Mb=Mb if stab else None)
 
     def gb_row(Gb, r):
         """Safe banded-G row read (negative rows -> zeros)."""
@@ -468,17 +510,17 @@ def plcg_scan(
                         / jnp.where(st.eta == 0, 1.0, st.eta))
         dkm1 = st.dlt[jnp.maximum(k - 1, 0)]
         eta_k = jnp.where(at_first, eta0, gam2[jnp.maximum(k, 0)] - lam * dkm1)
-        zeta_k = jnp.where(at_first, st.beta if stab else beta0,
-                           -lam * st.zeta)
+        zeta_k = jnp.where(at_first, st.beta, -lam * st.zeta)
         x2 = jnp.where(at_first, st.x, st.x + st.zeta * st.p)
         eta_safe = jnp.where(eta_k == 0, 1.0, eta_k)
         p2 = jnp.where(at_first, v_k / eta_safe,
                        (v_k - dkm1 * st.p) / eta_safe)
         return x2, p2, eta_k, zeta_k, st.k_done + 1
 
-    def finalize(st: PLCGState, ph, payload, q_aux, brk, x2, p2, eta2, zeta2,
-                 k2, Vw2, Zw2, Zhw2, Gb2, gam2, dlt2, *, reseed_now=None,
-                 seed_now=None, beta_new=None, seed_ok=None, beta2=None):
+    def finalize(st: PLCGState, bnorm, ph, payload, q_aux, brk, x2, p2, eta2,
+                 zeta2, k2, Vw2, Zw2, Zhw2, Gb2, gam2, dlt2, *,
+                 reseed_now=None, seed_now=None, beta_new=None,
+                 seed_ok=None, beta2=None):
         """Queue push + convergence/freeze commit, shared by both bodies.
 
         With the stability autopilot the classical commit select is
@@ -628,8 +670,8 @@ def plcg_scan(
         sig_arr = st.sig_c if use_ritz else sig
         return st.ph, reseed_now, seed_now, spmv_in, sig_arr
 
-    def stab_seed(st: PLCGState, t, t_hat, col_in_full, reseed_now, seed_now,
-                  sig_arr):
+    def stab_seed(st: PLCGState, lc: _LaneConsts, t, t_hat, col_in_full,
+                  reseed_now, seed_now, sig_arr):
         """Reseed stash + seed re-normalization values (stab only).
 
         Reseed body: t_hat = A x, so the true residual is rhat = b - t_hat
@@ -641,8 +683,8 @@ def plcg_scan(
         normalizes the stash into the init-state windows of a fresh solve
         started at x.
         """
-        rhat_new = bC - t_hat.astype(cdt)
-        r_new = (Mb - t.astype(cdt)) if prec is not None else rhat_new
+        rhat_new = lc.bC - t_hat.astype(cdt)
+        r_new = (lc.Mb - t.astype(cdt)) if prec is not None else rhat_new
         slotW = jnp.where(reseed_now, dot(rhat_new, r_new).astype(cdt),
                           jnp.asarray(0.0, cdt))
         beta2 = col_in_full[W]
@@ -676,10 +718,11 @@ def plcg_scan(
         return (slotW, beta2, seed_ok, beta_new, sel3,
                 (Vw_sd, Zw_sd, Zhw_sd), (Vw_st, Zw_st, Zhw_st))
 
-    def body(st: PLCGState, i):
+    def body(carry, i):
         # each phase runs under a named scope, so every HLO op of the body
         # carries its phase in its op_name metadata (plcg.spmv, .reduce,
         # .scalars, .recur, .dots, .update, .stab)
+        st, lc = carry
         ph, reseed_now, seed_now, spmv_in, sig_arr = stab_ctx(st, i)
         # ---------------- (K1) SPMV --------------------------------------
         # SPMV arithmetic runs in the compute dtype (on a mesh this keeps
@@ -764,8 +807,8 @@ def plcg_scan(
         if stab:
             with jax.named_scope("plcg.stab"):
                 (slotW, beta2, seed_ok, beta_new, sel3, seeded,
-                 stash) = stab_seed(st, t, t_hat, col_in_full, reseed_now,
-                                    seed_now, sig_arr)
+                 stash) = stab_seed(st, lc, t, t_hat, col_in_full,
+                                    reseed_now, seed_now, sig_arr)
                 # window selection BEFORE the payload dots so re-seeding
                 # lanes push dots of the stashed/seeded windows through the
                 # shared reduction (the seed body's payload IS fresh body
@@ -808,10 +851,12 @@ def plcg_scan(
             payload = jnp.concatenate([vd[::-1] * vmask, zd[::-1]])  # band
             if stab:
                 payload = jnp.concatenate([payload, slotW[None]])
-        return finalize(st, ph, payload, q_aux, brk, x2, p2, eta2, zeta2, k2,
-                        Vw2, Zw2, Zhw2, Gb2, gam2, dlt2, **seed_kw)
+        out, per_body = finalize(st, lc.bnorm, ph, payload, q_aux, brk, x2,
+                                 p2, eta2, zeta2, k2, Vw2, Zw2, Zhw2, Gb2,
+                                 gam2, dlt2, **seed_kw)
+        return (out, lc), per_body
 
-    def body_fused(st: PLCGState, i):
+    def body_fused(carry, i):
         """One launch per iteration: the fused_body megakernel computes
         (K1 when the stencil is fused) + (K4) + (K5); only the O(l^2)
         scalar recurrences (K2/K3/K6) stay in jnp.  With the stability
@@ -819,6 +864,7 @@ def plcg_scan(
         re-seed needs t/t_hat to assemble the true residual) and the
         payload dots are recomputed from the re-seed-selected windows --
         a documented small overhead of restart-enabled fused sweeps."""
+        st, lc = carry
         ph, reseed_now, seed_now, spmv_in, sig_arr = stab_ctx(st, i)
         c = ph - l + 1
         with jax.named_scope("plcg.reduce"):
@@ -880,8 +926,8 @@ def plcg_scan(
         if stab:
             with jax.named_scope("plcg.stab"):
                 (slotW, beta2, seed_ok, beta_new, sel3, seeded,
-                 stash) = stab_seed(st, t, t_hat, col_in_full, reseed_now,
-                                    seed_now, sig_arr)
+                 stash) = stab_seed(st, lc, t, t_hat, col_in_full,
+                                    reseed_now, seed_now, sig_arr)
                 Vw2 = sel3(seeded[0], stash[0], Vw2)
                 Zw2 = sel3(seeded[1], stash[1], Zw2)
                 if prec is not None:
@@ -908,17 +954,77 @@ def plcg_scan(
             payload = jnp.concatenate([vd[::-1] * vmask, zd[::-1]])
             if stab:
                 payload = jnp.concatenate([payload, slotW[None]])
-        return finalize(st, ph, payload, q_aux, brk, x2, p2, eta2, zeta2, k2,
-                        Vw2, Zw2, Zhw2, Gb2, gam2, dlt2, **seed_kw)
+        out, per_body = finalize(st, lc.bnorm, ph, payload, q_aux, brk, x2,
+                                 p2, eta2, zeta2, k2, Vw2, Zw2, Zhw2, Gb2,
+                                 gam2, dlt2, **seed_kw)
+        return (out, lc), per_body
 
-    final, (resnorms, committed) = jax.lax.scan(
-        body_fused if use_fused else body, state,
-        jnp.arange(iters), unroll=unroll)
+    agree = None
+    if comm is not None and comm.mode == "ring":
+        # ring devices accumulate the payload in their own order, so their
+        # done flags need not agree bit for bit: keep every device looping
+        # while any of them has a live lane
+        ring_axes = tuple(dict.fromkeys(hop[0] for hop in comm.schedule))
+        if ring_axes:
+            def agree(alive):
+                return jax.lax.pmax(alive.astype(jnp.int32), ring_axes) > 0
+    body_fn = body_fused if use_fused else body
+    if lanes:
+        carry = jax.vmap(init)(b, x0)
+        body_fn = jax.vmap(body_fn, in_axes=(0, None))
+    else:
+        carry = init(b, x0)
+    trips, (final, _), resnorms, committed = _run_bodies(
+        body_fn, carry, iters=iters, unroll=unroll, dtype=cdt, agree=agree)
+    if lanes:
+        resnorms, committed = resnorms.T, committed.T
+        trips = jnp.full(b.shape[:1], trips)
     return PLCGOut(x=final.x, resnorms=resnorms, k_done=final.k_done,
                    converged=final.converged, breakdown=final.breakdown,
                    committed=committed, restarts=final.restarts,
-                   replacements=final.repl,
-                   trips=jnp.asarray(iters, jnp.int32))
+                   replacements=final.repl, trips=trips)
+
+
+def _run_bodies(body, carry, *, iters: int, unroll: int, dtype, agree=None):
+    """Drive ``body(carry, i) -> (carry, (resnorm, committed))`` in a
+    ``lax.while_loop`` until every lane of ``carry[0].done`` is set or
+    ``iters`` bodies have run.
+
+    Each trip runs ``unroll`` bodies back to back and then re-evaluates
+    the exit (``agree``, when given, maps the local "some lane is still
+    live" flag to one every device shares); a second loop of single
+    bodies runs the ``iters % unroll`` that do not fill a trip, so no
+    body past ``iters`` ever runs.  The per-body outputs land in
+    preallocated ``(iters, *lanes)`` buffers, zero / False where no body
+    ran.  Returns ``(trips as int32, carry, resnorms, committed)``."""
+    k = max(1, int(unroll))
+    lane_shape = carry[0].done.shape
+    res0 = jnp.zeros((iters,) + lane_shape, dtype)
+    com0 = jnp.zeros((iters,) + lane_shape, bool)
+
+    def trip(width):
+        def run(loop):
+            i, _, c, res, com = loop
+            for u in range(width):
+                c, (r, m) = body(c, i + u)
+                res = jax.lax.dynamic_update_index_in_dim(res, r, i + u, 0)
+                com = jax.lax.dynamic_update_index_in_dim(com, m, i + u, 0)
+            alive = jnp.logical_not(jnp.all(c[0].done))
+            if agree is not None:
+                alive = agree(alive)
+            return i + width, alive, c, res, com
+        return run
+
+    def fits(width):
+        return lambda loop: (loop[0] + width <= iters) & loop[1]
+
+    # the body index has the dtype jnp.arange would give a scan
+    loop = (jnp.asarray(0), jnp.asarray(True), carry, res0, com0)
+    loop = jax.lax.while_loop(fits(k), trip(k), loop)
+    if k > 1 and iters % k:
+        loop = jax.lax.while_loop(fits(1), trip(1), loop)
+    i, _, carry, res, com = loop
+    return i.astype(jnp.int32), carry, res, com
 
 
 def plcg_jit(matvec, b, x0=None, *, l, iters, sigma, tol=0.0, prec=None,
@@ -1010,7 +1116,7 @@ def _jitted_sweep(matvec, l, iters, sigma, tol, prec, exploit_symmetry,
 
 def count_bodies(trips, l: int, k_done, committed=None,
                  lanes: Optional[int] = None) -> None:
-    """Add one sweep's scan bodies to the open root span's counters
+    """Add one sweep's bodies to the open root span's counters
     (``repro.core.telemetry``).
 
     ``bodies`` sums ``trips``, the fetched trip count the sweep returns

@@ -508,14 +508,16 @@ class Solver:
                     f"cannot micro-batch mixed RHS shapes "
                     f"{sorted({tuple(b.shape) for b in bs})}; "
                     "flush per shape")
-            bs += [bs[0]] * (pad - k)           # pad lanes: duplicate lane 0
+            # pad lanes duplicate lane 0, x0 included, so they finish with
+            # it and never keep the engine's loop running past the real
+            # lanes
+            bs += [bs[0]] * (pad - k)
             B = jnp.stack(bs)
             X0 = None
             if any(x0 is not None for _, x0, _ in chunk):
-                X0 = jnp.stack([jnp.zeros_like(bs[0]) if x0 is None
-                                else jnp.asarray(x0)
-                                for _, x0, _ in chunk]
-                               + [jnp.zeros_like(bs[0])] * (pad - k))
+                xs0 = [jnp.zeros_like(bs[0]) if x0 is None
+                       else jnp.asarray(x0) for _, x0, _ in chunk]
+                X0 = jnp.stack(xs0 + [xs0[0]] * (pad - k))
         rb = self._solve_batched_for_pool(B, X0, lanes=k)
         xs = telemetry.fetch(rb.x, "x")         # every lane's x, one read
         with telemetry.span("plcg.unpack"):

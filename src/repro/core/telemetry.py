@@ -26,7 +26,7 @@ memory.  Counters live on the root record, counted where the work
 happens:
 
   ``syncs``   the ``plcg.fetch`` spans under the root
-  ``bodies``  scan bodies the engine ran, the trip count each sweep
+  ``bodies``  bodies the engine ran, the exit trip each sweep
               returns, summed over lanes (padded lanes included) and
               over host-loop re-entries
   ``useful``  per real lane, the body index of its last committed update

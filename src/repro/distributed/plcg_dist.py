@@ -7,7 +7,7 @@ layer ``repro.core.solve(A, b, mesh=...)`` dispatches onto.  Any
 :class:`DistPoisson`) runs a registry method on the mesh:
 
   ============  =========================================================
-  ``plcg``      deep-pipelined p(l)-CG: ``jit(shard_map(vmap(plcg_scan)))``
+  ``plcg``      deep-pipelined p(l)-CG: ``jit(shard_map(plcg_scan))``
   ``plcg_scan`` alias of the same mesh engine (one scan engine everywhere)
   ``cg``        classic CG baseline: TWO synchronous psums per iteration
   ============  =========================================================
@@ -26,12 +26,14 @@ Per iteration of the pipelined engine:
 
 Batched multi-RHS: a ``(nrhs, nx, ny)`` right-hand side runs domain
 decomposition *inside* (``shard_map`` over the grid axes) and RHS
-batching *outside* (``vmap`` over lanes), so the per-iteration payload
-stacks to ``(nrhs, 2l+1)`` and the batched collective is STILL one psum
--- all lanes' reductions ride one fused all-reduce, the strong-scaling
-multi-solve workload of arXiv:1905.06850.  Convergence is masked per
-lane by the scan engine's commit select, identically to the
-single-device batched path.
+batching *outside* (``vmap`` of the engine body over lanes), so the
+per-iteration payload stacks to ``(nrhs, 2l+1)`` and the batched
+collective is STILL one psum -- all lanes' reductions ride one fused
+all-reduce, the strong-scaling multi-solve workload of arXiv:1905.06850.
+Convergence is masked per lane by the engine's commit select, and the
+loop stops when every lane is done, identically to the single-device
+batched path; every device computes the same exit predicate (see
+``plcg_scan``).
 
 Preconditioning composes: a structured ``repro.core.precond``
 preconditioner with a shard-local apply (``BlockJacobi`` -- zero
@@ -97,10 +99,10 @@ def _shard_jit(op: DistributedOperator, one, *, batched: bool,
     ``one(b_blk, x_blk, *extra)`` maps one local field block (plus
     ``n_extra`` replicated scalar operands, e.g. an iteration budget) to
     ``(x_blk, *n_out replicated scalar/trace outputs)``; with
-    ``batched`` the RHS lanes are vmapped OUTSIDE the domain
-    decomposition (extras are shared across lanes) and
-    ``trace_event(shape)``, when given, logs a compile event like the
-    single-device batched engine.
+    ``batched`` the blocks carry a leading lane axis that ``one`` handles
+    itself (extras are shared across lanes) and ``trace_event(shape)``,
+    when given, logs a compile event like the single-device batched
+    engine.
 
     ``ctx_specs`` (a pytree of ``PartitionSpec`` from a bindable
     operator's ``context_specs()``) prepends a traced context operand:
@@ -117,8 +119,7 @@ def _shard_jit(op: DistributedOperator, one, *, batched: bool,
                     and len(_engine.BATCH_TRACE_EVENTS) < 4096):
                 _engine.BATCH_TRACE_EVENTS.append(
                     trace_event(tuple(b_blk.shape)))
-            in_axes = (None,) * n_ctx + (0, 0) + (None,) * n_extra
-            return jax.vmap(one, in_axes=in_axes)(*args)
+            return one(*args)
         io_spec = _batch_spec(spec)
     else:
         local_run, io_spec = one, spec
@@ -202,7 +203,9 @@ def plcg_mesh_sweep(op: DistributedOperator, *, l: int, iters: int,
     ``psum_scatter`` at issue and an ``all_gather`` ``depth`` iterations
     later (zero bare psums in the scan body -- the reduction is
     structurally in flight); ``"ring"`` stages circulate-accumulate
-    ``ppermute`` hops across the queue shifts.  The policy is part of the
+    ``ppermute`` hops across the queue shifts (and agrees the loop's exit
+    with one scalar ``pmax`` per trip, since its devices sum in different
+    orders).  The policy is part of the
     sweep cache key; its operator capabilities are validated here via
     ``build_comm_runtime`` (prepared sessions validate earlier, at
     construction).
@@ -221,8 +224,10 @@ def plcg_mesh_sweep(op: DistributedOperator, *, l: int, iters: int,
         runtime = build_comm_runtime(policy, opref, l)
 
         def scan_body(matvec_local, b_blk, x_blk, k_budget):
+            # a batch keeps its lane axis: the engine vmaps its body
+            flat = b_blk.shape[:1] + (-1,) if batched else (-1,)
             out = plcg_scan(
-                matvec_local, b_blk.reshape(-1), x_blk.reshape(-1),
+                matvec_local, b_blk.reshape(flat), x_blk.reshape(flat),
                 l=l, iters=iters, sigma=sig, tol=tol,
                 prec=resolve(),
                 dot_local=opref.dot_local,
@@ -347,10 +352,14 @@ def cg_mesh_sweep(op: DistributedOperator, *, iters: int, tol: float = 0.0,
                 return cg_body(lambda v: opref.matvec_local_ctx(ctx, v),
                                b_blk, x_blk)
             ctx_specs = op.context_specs()
+            lane_axes = (None, 0, 0)
         else:
             def one(b_blk, x_blk):
                 return cg_body(opref.matvec_local, b_blk, x_blk)
             ctx_specs = None
+            lane_axes = (0, 0)
+        if batched:
+            one = jax.vmap(one, in_axes=lane_axes)
 
         return _shard_jit(op, one, batched=batched, ctx_specs=ctx_specs)
 

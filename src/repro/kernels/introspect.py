@@ -18,7 +18,9 @@ collective latency):
   slot block in the scan carry (:func:`scan_carry_shapes`).
 
 Counting equations in the traced jaxpr verifies all of this without
-running anything.
+running anything.  A "scan body" here is the body of any loop equation:
+a ``lax.scan``, or the ``lax.while_loop`` that drives the p(l)-CG engine
+until every lane is done.
 """
 from __future__ import annotations
 
@@ -55,11 +57,12 @@ def count_pallas_calls(fn, *args, **kwargs) -> int:
 
 def count_primitive_in_scan_bodies(fn, primitive: str, *args,
                                    **kwargs) -> list[int]:
-    """Per-``lax.scan``-body counts of ``primitive`` equations.
+    """Per-loop-body counts of ``primitive`` equations.
 
-    One entry per scan equation reachable from ``fn``'s jaxpr, in
-    traversal order -- i.e. the per-*iteration* cost of each loop.  For
-    the mesh solver sweeps (one scan) this returns ``[psums_per_iter]``.
+    One entry per scan or while equation reachable from ``fn``'s jaxpr,
+    in traversal order -- i.e. the per-*iteration* cost of each loop.
+    For the mesh solver sweeps (one loop) this returns
+    ``[psums_per_iter]``.
     """
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
     bodies: list = []
@@ -155,7 +158,7 @@ def _collect_collective_shapes(jaxpr, out: list, seen: set,
 
 
 def scan_carry_shapes(fn, *args, **kwargs) -> list[list[tuple]]:
-    """Per-scan carry layouts: one list of ``(shape...)`` tuples per scan
+    """Per-loop carry layouts: one list of ``(shape...)`` tuples per loop
     equation reachable from ``fn``'s jaxpr, in traversal order.
 
     The in-flight reduction queue lives in the scan carry, so its
@@ -180,6 +183,9 @@ def _collect_scan_carries(jaxpr, out: list, seen: set) -> None:
             nc, ncarry = eqn.params["num_consts"], eqn.params["num_carry"]
             out.append([tuple(v.aval.shape)
                         for v in eqn.invars[nc:nc + ncarry]])
+        elif eqn.primitive.name == "while":
+            nc = eqn.params["cond_nconsts"] + eqn.params["body_nconsts"]
+            out.append([tuple(v.aval.shape) for v in eqn.invars[nc:]])
         for sub in _sub_jaxprs(eqn.params):
             _collect_scan_carries(sub, out, seen)
 
@@ -204,6 +210,8 @@ def _collect_scan_bodies(jaxpr, out: list, seen: set) -> None:
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "scan":
             out.append(eqn.params["jaxpr"].jaxpr)
+        elif eqn.primitive.name == "while":
+            out.append(eqn.params["body_jaxpr"].jaxpr)
         for sub in _sub_jaxprs(eqn.params):
             _collect_scan_bodies(sub, out, seen)
 

@@ -158,32 +158,41 @@ def test_syncs_equal_the_reads_the_program_makes(problem, path,
 @pytest.mark.parametrize("path", ["default", "restart", "flush"])
 def test_bodies_are_the_trip_count_and_useful_the_committed_ones(problem,
                                                                  path):
+    """The engine stops after the body in which its last lane is done:
+    ``trips`` is that exit trip, below the cap of ``maxiter + l + 1``
+    (plus the stability slack), so a single solve's bodies are exactly
+    its useful ones and a flush's are every lane times the last lane's
+    useful bodies (the pad lane duplicates lane 0)."""
     A, B = problem
     restart = 2 if path == "restart" else None
     solver = Solver(A, **dict(KW, restart=restart))
-    trip = MAXITER + L + 1 + stab_iter_slack(L, restart, None, MAXITER)
+    cap = MAXITER + L + 1 + stab_iter_slack(L, restart, None, MAXITER)
     if path == "flush":
         pool = SolverPool(solver, max_batch=4, pad_to=(4,))
         handles = [pool.submit(b) for b in B[:3]]
         pool.flush()
         root = _last("solver.flush")
         iters = [h.result().iters for h in handles]
-        # the engine's own per-body output: (lanes, trip count)
+        last = max(L + k for k in iters)
+        # the engine's own per-body output on the flush's lanes: (lanes,
+        # exit trip)
         fn, args, _, _ = engine._batched_program(
-            solver.spec, solver._op, jnp.stack(list(B)), x0=None,
-            tol=solver.tol, maxiter=MAXITER, M=None, l=L, sigma=None,
-            spectrum=solver.spectrum, backend=None,
+            solver.spec, solver._op, jnp.stack(list(B[:3]) + [B[0]]),
+            x0=None, tol=solver.tol, maxiter=MAXITER, M=None, l=L,
+            sigma=None, spectrum=solver.spectrum, backend=None,
             get_engine=solver._batched_engine_getter())
-        assert np.asarray(fn(*args).trips).tolist() == [trip] * 4
-        assert root.counters["bodies"] == 4 * trip        # padding too
+        assert np.asarray(fn(*args).trips).tolist() == [last] * 4
+        assert last < cap
+        assert root.counters["bodies"] == 4 * last        # padding too
         assert root.counters["useful"] == sum(L + k for k in iters)
     else:
         r = solver.solve(B[0])
         root = _last("solver.solve")
         out = solver._single_sweep(solver.tol, MAXITER)(
             B[0], jnp.zeros_like(B[0]), MAXITER)
-        assert root.counters["bodies"] == int(out.trips) == trip
+        assert root.counters["bodies"] == int(out.trips) < cap
         assert root.counters["useful"] == L + r.iters
+        assert root.counters["bodies"] == root.counters["useful"]
     assert root.counters["useful"] <= root.counters["bodies"]
 
 
@@ -263,7 +272,9 @@ def test_every_phase_scope_reaches_the_compiled_hlo(problem, sweep, restart):
         solver = Solver(A, **dict(KW, restart=restart))
         b = B[0] if sweep == "single" else B
         txt = solver.lower(b).compile().as_text()
-        assert re.search(r'op_name="[^"]*/while/body/[^"]*plcg\.spmv/', txt)
+        # a batch vmaps the body inside the loop: ``vmap(plcg.spmv)/``
+        assert re.search(r'op_name="[^"]*/while/body/[^"]*plcg\.spmv[/)]',
+                         txt)
         found = sorted(set(re.findall(r'op_name="[^"]*?(plcg\.[a-z]+)',
                                       txt)))
     want = set(SCOPES) | ({"plcg.stab"} if restart is not None else set())
