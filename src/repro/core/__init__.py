@@ -37,7 +37,7 @@ from .linop import (BindableOperator, LinearOperator, dense_operator,
                     identity_preconditioner, is_bindable)
 from .precision import (PRECISION_MODES, PrecisionPolicy,
                         as_precision_policy)
-from .precond import (BlockJacobi, Chebyshev, Identity, Jacobi,
+from .precond import (BlockJacobi, Chebyshev, Identity, Jacobi, Multigrid,
                       Preconditioner, as_preconditioner, residual_gap)
 from .results import SolveResult
 from .session import SolveHandle, Solver, SolverPool
@@ -52,6 +52,7 @@ __all__ = [
     "Identity",
     "Jacobi",
     "LinearOperator",
+    "Multigrid",
     "PRECISION_MODES",
     "PrecisionPolicy",
     "Preconditioner",

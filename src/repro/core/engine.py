@@ -53,7 +53,7 @@ from .linop import LinearOperator, dense_operator, is_bindable
 from .pcg import ghysels_pcg
 from .plcg import plcg
 from .precision import as_precision_policy
-from .precond import as_preconditioner
+from .precond import Multigrid, as_preconditioner
 from .plcg_scan import plcg_solve, read_batched, resolve_backend
 from .plcg_scan import plcg_scan as _plcg_scan_engine
 from .plminres import plminres
@@ -336,18 +336,30 @@ def _prepare_options(spec: MethodSpec, options: dict) -> None:
             f"{accepted}")
 
 
-def _prepare_preconditioner(spec: MethodSpec, M):
-    """Normalize ``M`` once: bare callables promote to the Preconditioner
-    protocol, Identity collapses to the cheaper unpreconditioned pipeline,
-    and methods without the capability flag reject it up front -- every
-    downstream layer sees either None or a structured Preconditioner,
-    never a raw closure."""
-    M = as_preconditioner(M).runtime()
+#: preconditioners a configuration can name (``M="mg"``), each built from
+#: the operator at preparation
+NAMED_PRECONDITIONERS = {"mg": Multigrid}
+
+
+def _prepare_preconditioner(spec: MethodSpec, M, A=None):
+    """Normalize ``M`` once: a name in :data:`NAMED_PRECONDITIONERS` is
+    built from the operator ``A``, bare callables promote to the
+    Preconditioner protocol, Identity collapses to the cheaper
+    unpreconditioned pipeline, and methods without the capability flag
+    reject it up front -- every downstream layer sees either None or a
+    structured Preconditioner, never a raw closure."""
+    named = isinstance(M, str)
+    if named and M not in NAMED_PRECONDITIONERS:
+        raise TypeError(
+            f"unknown preconditioner name {M!r}; known names: "
+            f"{', '.join(sorted(NAMED_PRECONDITIONERS))}")
+    if not named:
+        M = as_preconditioner(M).runtime()
     if M is not None and not spec.supports_M:
         raise ValueError(
             f"method {spec.name!r} does not support preconditioning (M=); "
             f"methods with M= support: {', '.join(methods_supporting('M'))}")
-    return M
+    return NAMED_PRECONDITIONERS[M](A) if named else M
 
 
 def _prepare_spectrum(spec: MethodSpec, M, sigma, spectrum):
@@ -520,15 +532,16 @@ def _prepare_mesh_options(spec: MethodSpec, options: dict) -> None:
 
 
 def _prepare_knobs(spec: MethodSpec, *, M, backend, mesh, comm,
-                   precision=None, on_mesh: Optional[bool] = None):
+                   precision=None, on_mesh: Optional[bool] = None, A=None):
     """One-stop validation of the cross-cutting knob group (M= / mesh= /
     backend= / comm= / precision= -- see :data:`_KNOB_TABLE`): runs each
     knob's ``_prepare_*`` helper in table order and returns the
     normalized ``(M, comm, precision)`` triple.  ``on_mesh`` may be
     forced when the mesh path is selected by an operator rather than an
-    explicit ``mesh=``."""
+    explicit ``mesh=``; ``A`` is the operator a named ``M`` is built
+    from."""
     on_mesh = (mesh is not None) if on_mesh is None else on_mesh
-    M = _prepare_preconditioner(spec, M)
+    M = _prepare_preconditioner(spec, M, A)
     if on_mesh:
         _prepare_mesh_check(spec, backend)
     comm = _prepare_comm(spec, comm, on_mesh)
@@ -579,7 +592,10 @@ def solve(
         the Pallas megakernel via its ``inv_diag`` hint; ``BlockJacobi``
         / ``Chebyshev`` / constant-diagonal ``Jacobi`` run shard-local on
         a mesh) or any bare callable applying ``M^{-1} v`` (promoted via
-        :func:`repro.core.precond.as_preconditioner`).  ``Identity``
+        :func:`repro.core.precond.as_preconditioner`), or a name in
+        :data:`NAMED_PRECONDITIONERS` built from ``A`` (``"mg"``: HPCG's
+        multigrid V-cycle, :class:`repro.core.precond.Multigrid`, for an
+        operator with the ``stencil27`` hint).  ``Identity``
         collapses to the unpreconditioned pipeline.  Methods without the
         ``supports_M`` capability flag reject it up front.
       l: pipeline depth (pipelined methods only), or ``"auto"`` to pick
@@ -888,7 +904,7 @@ def _solve_batched_vmap(spec: MethodSpec, A: LinearOperator, B, *, x0, tol,
     (resnorms, conv, brk, k_done, restarts_pl, repl_pl) = read_batched(
         (out.resnorms, out.converged, out.breakdown, out.k_done,
          out.committed, out.restarts, out.replacements, out.trips),
-        l=l, stab=stab, lanes=lanes)
+        l=l, stab=stab, lanes=lanes, prec=M is not None)
     return SolveResult(
         x=out.x,
         resnorms=resnorms,

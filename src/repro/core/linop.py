@@ -26,6 +26,10 @@ class LinearOperator:
         unscaled 5-point Dirichlet Poisson stencil on that grid -- the
         structural hint that lets the ``backend="fused"`` scan engine fold
         the SPMV into its per-iteration Pallas megakernel.
+      stencil27: optional ``(nx, ny, nz)`` when the operator is HPCG's
+        27-point stencil on that grid (diagonal 26, the 26 neighbours -1,
+        zero Dirichlet; vectors in C order) -- the hint from which
+        ``repro.core.precond.Multigrid`` builds its V-cycle.
     """
 
     matvec: Callable[[Array], Array]
@@ -33,6 +37,7 @@ class LinearOperator:
     diag: Optional[Array] = None
     name: str = "A"
     stencil2d: Optional[tuple] = None
+    stencil27: Optional[tuple] = None
 
     def __matmul__(self, v: Array) -> Array:
         return self.matvec(v)

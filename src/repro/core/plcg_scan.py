@@ -402,8 +402,11 @@ def plcg_scan(
         x0 = x0.astype(cdt)
         bC = b.astype(cdt)   # scalar-side view of b (init/reseed residuals)
         rhat0 = bC - matvec(x0).astype(cdt)
-        r0 = prec(rhat0) if prec is not None else rhat0
-        Mb = prec(bC) if prec is not None else bC
+        if prec is not None:
+            with jax.named_scope("plcg.precond"):
+                r0, Mb = prec(rhat0), prec(bC)
+        else:
+            r0, Mb = rhat0, bC
         init_pay = jnp.stack([dot(rhat0, r0), dot(bC, Mb)]).astype(cdt)
         init_pay = red(init_pay)
         beta0 = jnp.sqrt(init_pay[0])
@@ -731,7 +734,11 @@ def plcg_scan(
         # megakernel tier stores.  Identity casts under the default policy.
         with jax.named_scope("plcg.spmv"):
             t_hat = matvec(spmv_in.astype(cdt)).astype(sdt)
-            t = prec(t_hat).astype(sdt) if prec is not None else t_hat
+        if prec is not None:
+            with jax.named_scope("plcg.precond"):
+                t = prec(t_hat).astype(sdt)
+        else:
+            t = t_hat
         # pop AFTER the SPMV + shard-local preconditioner apply in trace
         # order: with a split comm policy the head-of-queue gather is
         # issued here with no data dependence on t, so the prec apply is
@@ -888,16 +895,15 @@ def plcg_scan(
                 t_hat = kops.stencil2d_apply(
                     z2d, zr(z2d[0]), zr(z2d[0]), zr(z2d[:, 0]),
                     zr(z2d[:, 0]), use_pallas=True).reshape(-1)
-                t = prec(t_hat).astype(sdt) if prec is not None else t_hat
+                t = t_hat
             else:
                 # compute-dtype SPMV, storage-dtype streams (see body())
                 t_hat = matvec(spmv_in.astype(cdt)).astype(sdt)
-                if prec is None:
-                    t = t_hat
-                elif fuse_diag:
-                    t = None            # the kernel applies invd to t_hat
-                else:
-                    t = prec(t_hat).astype(sdt)
+                # with fuse_diag the kernel applies invd to t_hat
+                t = None if fuse_diag else t_hat
+        if prec is not None and t is not None:
+            with jax.named_scope("plcg.precond"):
+                t = prec(t_hat).astype(sdt)
         with jax.named_scope("plcg.fused"):
             Vw2, Zw2, Zhw2k, dots = kops.fused_body_apply(
                 st.Vw, st.Zw, st.Zhw if prec is not None else None,
@@ -1115,7 +1121,7 @@ def _jitted_sweep(matvec, l, iters, sigma, tol, prec, exploit_symmetry,
 
 
 def count_bodies(trips, l: int, k_done, committed=None,
-                 lanes: Optional[int] = None) -> None:
+                 lanes: Optional[int] = None, prec: bool = False) -> None:
     """Add one sweep's bodies to the open root span's counters
     (``repro.core.telemetry``).
 
@@ -1125,7 +1131,10 @@ def count_bodies(trips, l: int, k_done, committed=None,
     default; the rest are padding), the body index of each lane's last
     committed update plus one: from the fetched ``committed`` mask where
     the caller has it, else ``l + k_done + 1`` (update k commits at body
-    l + k)."""
+    l + k).  With a preconditioner (``prec``) ``precond_applies`` adds
+    the applies the sweep ran: two in its init (``M r0`` and ``M b``; an
+    in-scan re-seed reuses the stashed ``M b``) and one a body, frozen or
+    not, on every lane."""
     if committed is not None:
         m = np.asarray(committed, dtype=bool)
         m = m.reshape(-1, m.shape[-1])
@@ -1135,16 +1144,21 @@ def count_bodies(trips, l: int, k_done, committed=None,
         last = l + np.asarray(k_done).reshape(-1) + 1
     telemetry.count("bodies", int(np.sum(trips)))
     telemetry.count("useful", int(last[:lanes].sum()))
+    if prec:
+        telemetry.count("precond_applies",
+                        int(np.sum(np.asarray(trips) + 2)))
 
 
-def read_batched(out, *, l: int, stab: bool, lanes: Optional[int] = None):
+def read_batched(out, *, l: int, stab: bool, lanes: Optional[int] = None,
+                 prec: bool = False):
     """The host side of one batched sweep: ``out`` holds its device
     ``(resnorms, converged, breakdown, k_done, committed, restarts,
     replacements, trips)``, each lane's row first.  Reads them in that
     order, one ``plcg.fetch`` each (``committed`` / ``restarts`` /
     ``replacements`` only on the in-scan ``stab`` path; ``trips`` rides
     the ``k_done`` read), counts the sweep's bodies
-    (:func:`count_bodies`; lanes past the first ``lanes`` are padding) and
+    (:func:`count_bodies`; lanes past the first ``lanes`` are padding,
+    ``prec`` says whether the sweep applied a preconditioner) and
     builds each lane's residual history in a ``plcg.unpack`` span.
     Returns ``(resnorms lists, converged, breakdown, k_done, restarts,
     replacements)`` on the host."""
@@ -1160,12 +1174,13 @@ def read_batched(out, *, l: int, stab: bool, lanes: Optional[int] = None):
         committed = telemetry.fetch(committed, "committed", dtype=bool)
         restarts = telemetry.fetch(restarts, "restarts")
         repl = telemetry.fetch(repl, "replacements")
-        count_bodies(trips, l, k_done, committed=committed, lanes=lanes)
+        count_bodies(trips, l, k_done, committed=committed, lanes=lanes,
+                     prec=prec)
         with telemetry.span("plcg.unpack"):
             resnorms = [[float(r) for r in row[m]]
                         for row, m in zip(resn, committed)]
         return resnorms, conv, brk, k_done, restarts, repl
-    count_bodies(trips, l, k_done, lanes=lanes)
+    count_bodies(trips, l, k_done, lanes=lanes, prec=prec)
     # lane j commits |zeta_k| for k = 0..k_done[j] at trace indices
     # l..l+k_done[j]; slicing by count (not value-filtering) keeps a
     # legitimate exact-zero residual in the trace
@@ -1178,7 +1193,8 @@ def read_batched(out, *, l: int, stab: bool, lanes: Optional[int] = None):
 
 def run_restart_driver(sweep, b, x0, *, tol: float, maxiter: int,
                        max_restarts: int, bnorm: float, l: int,
-                       in_scan: bool = False, program=None):
+                       in_scan: bool = False, program=None,
+                       prec: bool = False):
     """Restart-on-breakdown with a global iteration budget (paper
     Remark 8), shared by the single-device and mesh drivers -- the ONE
     place restart semantics (budget accounting, happy breakdown,
@@ -1198,8 +1214,8 @@ def run_restart_driver(sweep, b, x0, *, tol: float, maxiter: int,
     .. deprecated:: its shift-free re-init (the restarted sweep reuses
        the original sigma instead of Ritz-refreshing) and its
        single-RHS-only reach are superseded by the in-scan path.
-    The loop reads ``x``, ``resnorms``, ``converged``, ``breakdown``,
-    ``k_done`` and ``trips`` of each pass.
+    The loop reads ``x``, ``resnorms``, ``k_done`` (with ``trips``) and
+    ``converged`` (with ``breakdown``) of each pass.
 
     Either way a breakdown-looping system performs at most ``maxiter``
     updates in total (not ``max_restarts x maxiter``); happy breakdown
@@ -1210,7 +1226,8 @@ def run_restart_driver(sweep, b, x0, *, tol: float, maxiter: int,
     jitted callable it runs, tells whether the call compiled; ``sweep``
     itself by default), a ``plcg.wait`` and one ``plcg.fetch`` per read
     of an output (``trips`` rides the ``k_done`` read), and counts its
-    bodies (:func:`count_bodies`, depth ``l``) on the open root span.
+    bodies (:func:`count_bodies`, depth ``l``; ``prec`` says whether the
+    sweep applies a preconditioner) on the open root span.
     """
     if in_scan:
         out = telemetry.dispatch(sweep, b, x0, maxiter, program=program)
@@ -1225,7 +1242,7 @@ def run_restart_driver(sweep, b, x0, *, tol: float, maxiter: int,
         n_repl = int(telemetry.fetch(n_repl, "replacements"))
         k_done, trips = telemetry.fetch((k_done, trips), "k_done")
         k_done = int(k_done)
-        count_bodies(trips, l, k_done, committed=mask)
+        count_bodies(trips, l, k_done, committed=mask, prec=prec)
         with telemetry.span("plcg.unpack"):
             resnorms = [float(r) for r in resn_h[mask]]
         if (not converged and breakdown and resnorms
@@ -1267,12 +1284,15 @@ def run_restart_driver(sweep, b, x0, *, tol: float, maxiter: int,
             resnorms.extend(float(r) for r in resn_h if r > 0)
         k, trips = telemetry.fetch((k_done, out[8]), "k_done")
         k = int(k)
-        count_bodies(trips, l, k)
+        count_bodies(trips, l, k, prec=prec)
         total_k += max(k + 1, 1)
-        if bool(telemetry.fetch(conv, "converged")):
+        # one read for both flags: a solve that stops on its budget (as
+        # with tol=0) needs the breakdown flag too
+        conv, brk = telemetry.fetch((conv, brk), "converged")
+        if bool(conv):
             converged = True
             break
-        if bool(telemetry.fetch(brk, "breakdown")):
+        if bool(brk):
             breakdowns += 1
             if resnorms and resnorms[-1] <= 4 * tol * bnorm:
                 converged = True          # happy breakdown at tolerance
@@ -1350,4 +1370,5 @@ def plcg_solve(matvec, b, x0=None, *, l, sigma, tol=1e-8, maxiter=1000,
 
     return run_restart_driver(run_sweep, b, x0, tol=tol, maxiter=maxiter,
                               max_restarts=max_restarts, bnorm=bnorm, l=l,
-                              in_scan=in_scan, program=program)
+                              in_scan=in_scan, program=program,
+                              prec=prec is not None)

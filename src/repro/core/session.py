@@ -176,7 +176,7 @@ class Solver:
         # below re-validates per call
         M, comm, precision = engine._prepare_knobs(
             spec, M=M, backend=backend, mesh=mesh, comm=comm,
-            precision=precision, on_mesh=on_mesh)
+            precision=precision, on_mesh=on_mesh, A=A)
         l = engine._prepare_depth(spec, l)
         restart, residual_replacement = engine._prepare_restart(
             spec, restart, residual_replacement, options)

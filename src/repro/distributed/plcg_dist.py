@@ -465,7 +465,7 @@ def _mesh_plcg(op, b, x0, *, tol, maxiter, l, sigma, prec=None,
         out = telemetry.dispatch(fn, b, x0, maxiter + 1, program=program)
         telemetry.wait(out)
         (resnorms, conv, brk, k_done, restarts_pl, repl_pl) = read_batched(
-            out[1:], l=l, stab=stab, lanes=lanes)
+            out[1:], l=l, stab=stab, lanes=lanes, prec=prec is not None)
         return SolveResult(
             x=out[0].reshape(orig_shape),
             resnorms=resnorms,
@@ -499,7 +499,7 @@ def _mesh_plcg(op, b, x0, *, tol, maxiter, l, sigma, prec=None,
         fn, b, x0, tol=tol, maxiter=maxiter,
         max_restarts=5 if max_restarts is None else max_restarts,
         bnorm=float(telemetry.fetch(norm, "bnorm")) or 1.0, l=l,
-        in_scan=stab, program=program)
+        in_scan=stab, program=program, prec=prec is not None)
     return SolveResult(
         x=x.reshape(orig_shape), resnorms=resnorms,
         iters=info["iterations"], converged=info["converged"],
